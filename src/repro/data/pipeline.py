@@ -37,6 +37,12 @@ class Pipeline:
         self._drain()
         self._step = step
 
+    def close(self):
+        """Stop the prefetch thread.  Call before the process exits: a
+        daemon thread still inside a device transfer while the interpreter
+        shuts down aborts the process."""
+        self._drain()
+
     # -------- iteration --------
     def _producer(self, start: int):
         s = start

@@ -25,11 +25,13 @@ def _ceil_to(x: int, m: int) -> int:
 # pack
 # --------------------------------------------------------------------------
 def _pack_kernel(g_ref, o_ref):
-    bits = (g_ref[...] >= 0).astype(jnp.uint32)             # (bw, 32)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, bits.shape, 1)
-    o_ref[...] = jnp.sum(bits << shifts, axis=1,
-                         dtype=jnp.uint32)                  # or-free: bits
-    # distinct bit positions => sum == bitwise-or
+    # Mosaic has no unsigned reductions: build the word in int32 (bit 31
+    # lands on the sign, and the wrapping sum of distinct bit positions is
+    # still their bitwise-or), then reinterpret the bits as uint32.
+    bits = (g_ref[...] >= 0).astype(jnp.int32)              # (bw, 32)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, bits.shape, 1)
+    word = jnp.sum(bits << shifts, axis=1, dtype=jnp.int32)
+    o_ref[...] = jax.lax.bitcast_convert_type(word, jnp.uint32)
 
 
 def pack_signs(g: jax.Array, *, bw: int = 2048,

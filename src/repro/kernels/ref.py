@@ -84,7 +84,9 @@ def qsgd_quantize(g: jax.Array, norm: jax.Array, levels: int,
 
     E[dequantize(q)] = g  (unbiased).
     """
-    scaled = jnp.abs(g) / norm * levels          # in [0, levels]
+    # the kernel's arithmetic, op for op, so the two agree bit for bit
+    scale = jnp.float32(levels) / (norm + 1e-12)
+    scaled = jnp.abs(g).astype(jnp.float32) * scale   # in [0, levels]
     low = jnp.floor(scaled)
     prob = scaled - low
     up = jax.random.bernoulli(key, prob)

@@ -23,8 +23,10 @@ def make_test_mesh(shape=(2, 2, 2), axes=("pod", "data", "model")):
 
 
 def make_local_mesh():
-    """Single-device mesh (CPU examples)."""
-    return _mk((1, 1), ("data", "model"))
+    """Every visible device on ``data`` (one chip, a four-chip host, or
+    ``(1, 1)`` on a single CPU device)."""
+    import jax
+    return _mk((jax.device_count(), 1), ("data", "model"))
 
 
 def make_pod_mesh(procs: int | None = None, local: int | None = None,

@@ -1,19 +1,24 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> ...``
 
-CPU-scale entry point (reduced configs by default) exercising the REAL
-production path: mesh -> TrainSetup -> sharded state -> Trainer with
-checkpointing, preemption handling and optional local-SGD.  On a real TPU
-fleet the same module runs with --mesh single/multi and full configs.
+Entry point (reduced configs by default) exercising the REAL production
+path: mesh -> TrainSetup -> sharded state -> Trainer with checkpointing,
+preemption handling and optional local-SGD.  ``--mesh local`` puts every
+visible device on the data axis (one chip, or the four chips of a host);
+``--full-size --layers N`` runs a config at its published widths with its
+depth cut to N layers.  ``--mesh single/multi`` is the pod-scale layout.
 """
 import argparse
 import os
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--full-size", action="store_true",
                     help="use the full config (default: reduced smoke size)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to this many layers, widths "
+                         "unchanged (0: the config's own depth)")
     ap.add_argument("--mesh", default="local",
                     choices=["local", "test", "single", "multi", "pod"])
     ap.add_argument("--devices", type=int, default=0,
@@ -59,8 +64,13 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def prepare(args: argparse.Namespace):
+    """Everything before the training loop: process flags, the compile
+    cache, the config (depth cut included), the mesh, the TrainSetup and
+    the data pipeline.  Returns ``(setup, data)``."""
     if args.mesh == "test" and args.devices:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
@@ -71,13 +81,18 @@ def main(argv=None):
             + f" --xla_force_host_platform_device_count="
               f"{args.local_devices}")
     if args.overlap or args.adaptive:
-        # latency-hiding-scheduler flags must precede jax init (TPU only);
-        # adaptive resolves to an overlapped plan even on fallback
+        # latency-hiding-scheduler flags must precede jax init (libtpu
+        # reads them once); adaptive resolves to an overlapped plan even
+        # on fallback
         from repro.train.overlap import enable_overlap_flags
         enable_overlap_flags()
 
+    import dataclasses
+
     import jax
-    import jax.numpy as jnp
+
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     if args.mesh == "pod":
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
@@ -90,12 +105,17 @@ def main(argv=None):
     from repro.data.synthetic import DataConfig
     from repro.launch import mesh as mesh_mod
     from repro.train import train_step as ts
-    from repro.train.schedule import ScheduleConfig
-    from repro.train.trainer import Trainer, TrainerConfig
 
     arch = cfgs.get(args.arch)
     if not args.full_size:
         arch = cfgs.reduced(arch)
+    depth = f"n_layers={arch.n_layers}"
+    if args.layers:
+        if not 1 <= args.layers <= arch.n_layers:
+            raise ValueError(f"--layers {args.layers} outside 1.."
+                             f"{arch.n_layers} for {arch.name}")
+        depth = f"n_layers={args.layers} (cut from {arch.n_layers})"
+        arch = dataclasses.replace(arch, n_layers=args.layers)
     if args.mesh == "local":
         mesh = mesh_mod.make_local_mesh()
     elif args.mesh == "pod":
@@ -125,8 +145,6 @@ def main(argv=None):
                   f"(arch plan had dp_mode={arch.plan.dp_mode!r})")
         overrides.update(overlap=True, dp_mode="ddp")
     if args.adaptive:
-        import dataclasses
-
         from repro.adaptive import controller as actl
         plan = dataclasses.replace(arch.plan, **overrides)
         if plan.dp_mode != "ddp":
@@ -146,7 +164,10 @@ def main(argv=None):
     if setup.overlap:
         from repro.train import overlap as overlap_mod
         sched = f" overlap={overlap_mod.effective_schedule(setup)}"
-    print(f"[train] arch={arch.name} mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
+    print(f"[train] arch={arch.name} {depth} d_model={arch.d_model} "
+          f"d_ff={arch.d_ff} heads={arch.n_heads}/{arch.n_kv_heads} "
+          f"vocab={arch.vocab} seq={args.seq} batch={args.batch} "
+          f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
           f"dp_mode={setup.arch.plan.dp_mode} zero1={setup.zero1} "
           f"fsdp={setup.fsdp_axes} accum={args.accum} "
           f"agg={setup.agg_cfg.compressor}@{setup.agg_cfg.compress_axes}"
@@ -166,6 +187,9 @@ def main(argv=None):
                 self.inner, self.setup = inner, setup
                 self._specs_fn = ts.make_batch_specs(setup)
 
+            def close(self):
+                self.inner.close()
+
             def __iter__(self):
                 for b in self.inner:
                     specs = self._specs_fn(b)
@@ -175,6 +199,21 @@ def main(argv=None):
                            for k, v in b.items()}
 
         data = _GlobalBatches(data, setup)
+    return setup, data
+
+
+def main(argv=None):
+    """Parse, prepare, train; returns the :class:`Trainer` (its
+    ``history`` holds the logged steps, its ``state`` the final state;
+    its data pipeline is closed)."""
+    args = parse_args(argv)
+    setup, data = prepare(args)
+
+    import jax
+
+    from repro.train.schedule import ScheduleConfig
+    from repro.train.trainer import Trainer, TrainerConfig
+
     tcfg = TrainerConfig(
         total_steps=args.steps, log_every=args.log_every,
         ckpt_every=args.ckpt_every, ckpt_dir=args.ckpt_dir,
@@ -182,8 +221,12 @@ def main(argv=None):
         schedule=ScheduleConfig(peak_lr=args.lr, warmup_steps=args.warmup,
                                 total_steps=args.steps))
     trainer = Trainer(setup, tcfg, data)
-    state = trainer.run(jax.random.key(args.seed))
+    try:
+        state = trainer.run(jax.random.key(args.seed))
+    finally:
+        data.close()
     print(f"[train] done at step {int(jax.device_get(state['step']))}")
+    return trainer
 
 
 if __name__ == "__main__":

@@ -52,17 +52,12 @@ def _remat(fn, mode: str):
     """Block-level rematerialization.  The wrapped fn's positional args pass
     through optimization_barrier: the backward pass consumes per-layer
     slices of the saved activation stack, and without the barrier XLA
-    hoists convert(slice(stack)) into a whole-stack fp32 copy.  The
-    AD-safe wrapper (``compat.ad_optimization_barrier``) keeps the
-    barrier in the primal while passing cotangents through — the pinned
-    jax has no differentiation rule for the raw primitive."""
+    hoists convert(slice(stack)) into a whole-stack fp32 copy."""
     if mode == "none":
         return fn
 
-    from repro.parallel.compat import ad_optimization_barrier
-
     def barriered(*args, **kw):
-        args = ad_optimization_barrier(args)
+        args = jax.lax.optimization_barrier(args)
         return fn(*args, **kw)
 
     if mode == "dots":

@@ -1,58 +1,17 @@
-"""Version-compat shims for jax APIs that moved between releases.
-
-The repo supports jax >= 0.4.3x (CI's pinned ``jax[cpu]``) through current:
-``shard_map`` graduated from ``jax.experimental`` (gaining ``check_vma`` in
-place of ``check_rep``), and ``jax.make_mesh`` grew ``axis_types``.  Every
-mesh/shard_map construction in src, tests, and benchmarks goes through
-these two helpers.
+"""The repo's two mesh helpers: every mesh/shard_map construction in src,
+tests, and benchmarks goes through them, so the axis types and the
+shard_map checking mode are chosen in one place.
 """
 from __future__ import annotations
 
 import jax
 
-try:
-    from jax import shard_map as _shard_map          # jax >= 0.6
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(shape, axes):
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):  # older jax without axis_types
-        return jax.make_mesh(shape, axes)
-
-
-@jax.custom_vjp
-def ad_optimization_barrier(args):
-    """``jax.lax.optimization_barrier`` that is safe under differentiation.
-
-    The pinned jax (0.4.37) has no AD rule for ``optimization_barrier``,
-    so barriers inside a differentiated forward (``model._remat`` pins
-    per-layer slices of the saved activation stack against whole-stack
-    fp32 hoisting) raise ``NotImplementedError`` at trace time.  The
-    barrier's job is entirely in the primal program — keep it there (the
-    checkpointed forward replay still emits it) and pass cotangents
-    through unchanged."""
-    return jax.lax.optimization_barrier(args)
-
-
-def _ad_ob_fwd(args):
-    return ad_optimization_barrier(args), None
-
-
-def _ad_ob_bwd(_, cts):
-    return (cts,)
-
-
-ad_optimization_barrier.defvjp(_ad_ob_fwd, _ad_ob_bwd)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -23,7 +23,7 @@ closes that model-vs-execution gap:
      backward stages, pinned in program order with
      ``jax.lax.optimization_barrier`` so XLA cannot sink the collectives
      behind the remaining backward; the latency-hiding-scheduler flags
-     (:data:`XLA_OVERLAP_FLAGS`) then hide each collective under the next
+     (:data:`TPU_OVERLAP_FLAGS`) then hide each collective under the next
      stage's compute.  ``schedule="serial"`` runs the *same* segmented
      backward and the *same* per-bucket aggregation but issues every
      collective after the full backward — the two schedules are
@@ -66,7 +66,6 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -75,10 +74,12 @@ from jax.sharding import PartitionSpec as P
 from repro.core import aggregator as agg_mod
 from repro.core import bucketing
 
-#: XLA flags that let the latency-hiding scheduler overlap the pinned
-#: collectives with backward compute (TPU; harmless elsewhere).  Must be in
-#: XLA_FLAGS *before* jax initializes — see :func:`enable_overlap_flags`.
-XLA_OVERLAP_FLAGS = (
+#: libtpu flags that let the latency-hiding scheduler overlap the pinned
+#: collectives with backward compute.  They belong in ``LIBTPU_INIT_ARGS``,
+#: which only libtpu reads: in ``XLA_FLAGS`` the CPU client (which JAX also
+#: creates on a TPU host) aborts on the unknown ``--xla_tpu_*`` names.
+#: See :func:`enable_overlap_flags`.
+TPU_OVERLAP_FLAGS = (
     "--xla_tpu_enable_latency_hiding_scheduler=true "
     "--xla_tpu_enable_async_collective_fusion=true "
     "--xla_tpu_enable_async_collective_fusion_fuse_all_reduce=true")
@@ -96,32 +97,16 @@ def _stack_keys(family: str) -> tuple[str, ...]:
     return (_STACK_KEYS[family],)
 
 
-def enable_overlap_flags(tpu: Optional[bool] = None) -> None:
-    """Append :data:`XLA_OVERLAP_FLAGS` to ``XLA_FLAGS`` (idempotent).
-    Call before the first jax import — flags set later are ignored.
-    No-op off-TPU: XLA *aborts the process* on unknown ``--xla_tpu_*``
-    flags, and CPU/GPU have no latency-hiding scheduler to enable.
-
-    ``tpu=None`` auto-detects pre-jax-init: an explicit ``JAX_PLATFORMS``
-    wins; otherwise a TPU is assumed only when BOTH libtpu is importable
-    and a ``/dev/accel*`` device node exists (libtpu alone is just a
-    wheel — CPU containers ship it too, and the flags would abort there).
-    """
-    import glob
-    import importlib.util
+def enable_overlap_flags() -> None:
+    """Append :data:`TPU_OVERLAP_FLAGS` to ``LIBTPU_INIT_ARGS``
+    (idempotent).  Call before jax initializes its backends: libtpu reads
+    the variable once, when it loads.  No host detection is needed —
+    where libtpu never loads, nothing reads the variable."""
     import os
-    if tpu is None:
-        env = os.environ.get("JAX_PLATFORMS", "").lower()
-        if env:
-            tpu = "tpu" in env
-        else:
-            tpu = (importlib.util.find_spec("libtpu") is not None
-                   and bool(glob.glob("/dev/accel*")))
-    if not tpu:
-        return
-    cur = os.environ.get("XLA_FLAGS", "")
+    cur = os.environ.get("LIBTPU_INIT_ARGS", "")
     if "latency_hiding_scheduler" not in cur:
-        os.environ["XLA_FLAGS"] = (cur + " " + XLA_OVERLAP_FLAGS).strip()
+        os.environ["LIBTPU_INIT_ARGS"] = \
+            (cur + " " + TPU_OVERLAP_FLAGS).strip()
 
 
 # --------------------------------------------------------------------------

@@ -96,7 +96,9 @@ def main(argv=None) -> None:
 
     # mutate XLA_FLAGS before ANY repro/jax import — repro.train.overlap
     # pulls in the jax import chain, and flags set after jax initializes
-    # are silently ignored
+    # are silently ignored.  The forced host devices are a CPU stand-in:
+    # pin the CPU platform so a run on a TPU host never opens the chip.
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={args.devices}")

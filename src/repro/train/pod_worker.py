@@ -70,7 +70,9 @@ def main(argv=None) -> None:
                          "stdout line")
     args = ap.parse_args(argv)
 
-    # flags before ANY repro/jax import (same contract as overlap_bench)
+    # flags before ANY repro/jax import (same contract as overlap_bench,
+    # CPU platform pinned likewise)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={args.local_devices}")

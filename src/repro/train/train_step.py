@@ -179,10 +179,15 @@ def build(arch: ArchConfig, mesh: Mesh,
         agg_cfg = dataclasses.replace(agg_cfg, compress_axes=comp,
                                       raw_axes=())
     else:
+        # compress_axes="all" keeps a one-device DP axis: the compressor's
+        # encode and decode then run although the collective is a no-op
+        # (one chip runs the compressed step this way)
+        forced = plan.compress_axes == "all"
         agg_cfg = dataclasses.replace(
             agg_cfg,
             compress_axes=tuple(a for a in agg_cfg.compress_axes
-                                if sizes.get(a, 1) > 1),
+                                if sizes.get(a, 1) > 1
+                                or (forced and a in names)),
             raw_axes=tuple(a for a in agg_cfg.raw_axes
                            if sizes.get(a, 1) > 1))
     # fail at build time (not mid-step on a live pod) when a hierarchical

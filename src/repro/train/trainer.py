@@ -91,7 +91,7 @@ class Trainer:
 
         start_step = int(jax.device_get(self.state["step"]))
         it = iter(self.data)
-        t0 = time.time()
+        t0 = t_log = time.time()
         tokens_acc = 0
         for step in range(start_step, cfg.total_steps):
             if step > start_step:
@@ -103,11 +103,17 @@ class Trainer:
                 self.state = self.sync_fn(self.state)
             if cfg.log_every and (step + 1) % cfg.log_every == 0:
                 m = jax.device_get(metrics)
+                now = time.time()
                 tokens_acc += int(m["tokens"]) * cfg.log_every
-                dt = time.time() - t0
+                dt = now - t0
+                # host seconds per step since the last log; the metrics
+                # fetch waits for the device, so the first interval
+                # includes compilation
                 rec = dict(step=step + 1, loss=float(m["loss"]),
                            grad_norm=float(m["grad_norm"]), lr=lr,
-                           tok_per_s=tokens_acc / max(dt, 1e-9))
+                           tok_per_s=tokens_acc / max(dt, 1e-9),
+                           step_s=(now - t_log) / cfg.log_every)
+                t_log = now
                 self.history.append(rec)
                 print(f"step {rec['step']:>6d}  loss {rec['loss']:.4f}  "
                       f"gnorm {rec['grad_norm']:.3f}  lr {lr:.2e}  "

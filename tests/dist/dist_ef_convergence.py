@@ -11,11 +11,21 @@ delivers the ACCUMULATED gradient — an effective per-selection step of
 ~lr * n/k — and the iterate converges to a small fraction of the
 initial loss on the same budget.
 
-Assertions (constants frozen from the tuning sweep):
+Assertions:
   * ef:randomk final loss <= 1e-2 * L0            (converged)
   * plain randomk final loss >= 0.5 * L0          (plateaued)
-  * plain final/mid-loss ratio >= 0.8             (near-flat tail)
+  * plain final / three-quarter-loss ratio >= 0.8 (near-flat tail)
   * ef beats plain by >= 20x
+
+The budget is T = 1000 steps.  At 500 steps the ef:randomk iterate is
+still decaying (about 0.3 L0 at step 100, 0.03 L0 at step 400) and its
+tail is spiky, so the step-500 loss depends on the random draws: over six
+seeded streams it spans 0.0036-0.0111 L0, and the 1e-2 bound sat inside
+that spread.  (JAX 0.5 made ``jax_threefry_partitionable`` the default,
+which changed every seeded draw: with it off, step 500 gives 0.0059 L0;
+with it on, 0.0111 L0.)  At 1000 steps the same six streams end at or below
+0.0005 L0 (at most 0.0037 L0 anywhere in the last 100 steps), and plain
+randomk ends near 0.68 L0 with a final / step-750 ratio near 0.90.
 """
 import harness
 
@@ -30,7 +40,7 @@ from repro.parallel.compat import make_mesh, shard_map  # noqa: E402
 
 N = 512
 N_DEV = 4
-T = 500
+T = 1000
 LR = 0.01
 FRAC = 0.02
 
@@ -71,7 +81,7 @@ def main():
     plain = run("randomk", dict(frac=FRAC, error_feedback=False), x_star)
     ef = run("ef:randomk", dict(frac=FRAC), x_star)
 
-    plateau = plain[-1] / plain[T // 2 - 1]
+    plateau = plain[-1] / plain[3 * T // 4 - 1]
     print(f"  L0 {l0:.2f}")
     print(f"  plain randomk   final {plain[-1]:.3f} "
           f"({plain[-1] / l0:.3f} L0), tail ratio {plateau:.3f}")

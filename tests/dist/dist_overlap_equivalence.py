@@ -14,7 +14,8 @@ differs.  Training results must therefore be bit-identical, for the raw
   * the unfused two-dispatch strawman agrees to fp tolerance;
   * the enc-dec (audio) family — two segmented stacks, decoder then
     encoder, under its default ZeRO-1 plan — is bit-identical
-    serial-vs-overlapped and fp-agrees with the classic step.
+    serial-vs-overlapped and fp-agrees with the classic step;
+  * ``make_local_mesh`` spans all four devices on the data axis.
 
 (The ZeRO-1 × accum regime matrix has its own oracle:
 tests/dist/dist_zero1_accum.py.)
@@ -29,6 +30,7 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs import base  # noqa: E402
+from repro.launch.mesh import make_local_mesh  # noqa: E402
 from repro.parallel.compat import make_mesh  # noqa: E402
 from repro.train import overlap  # noqa: E402
 from repro.train import train_step as ts  # noqa: E402
@@ -37,7 +39,18 @@ STEPS = 3
 METHODS = ["none", "randomk", "signsgd"]
 
 
+def local_mesh_spans_devices():
+    """``--mesh local`` (``make_local_mesh``) puts every visible device on
+    the data axis: here the four virtual devices, on a host the chips."""
+    mesh = make_local_mesh()
+    assert dict(zip(mesh.axis_names, mesh.devices.shape)) == \
+        {"data": 4, "model": 1}, mesh
+    assert set(mesh.devices.flat) == set(jax.devices())
+    print("  make_local_mesh: data=4 over all 4 devices")
+
+
 def main():
+    local_mesh_spans_devices()
     batches = harness.make_batches(STEPS)
 
     for method in METHODS:

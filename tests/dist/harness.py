@@ -30,9 +30,11 @@ DEFAULT_DEVICES = 4
 
 
 def setup_devices(n: int = DEFAULT_DEVICES) -> None:
-    """Force ``n`` fake host devices; must precede the jax import."""
+    """Force ``n`` fake host devices on the CPU platform (never a chip,
+    even on a TPU host); must precede the jax import."""
     assert "jax" not in sys.modules, \
         "harness.setup_devices() called after jax was imported"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={n}")

@@ -46,6 +46,19 @@ def test_pipeline_cursor_exact_restart():
                                   np.asarray(batches[3]["tokens"]))
 
 
+def test_pipeline_close_stops_prefetch_thread():
+    """No prefetch thread may outlive ``close`` (a daemon thread inside a
+    device transfer at interpreter exit aborts the process)."""
+    import threading
+    before = threading.active_count()
+    p = Pipeline(DataConfig(vocab=11, seq_len=8, global_batch=2),
+                 prefetch=2)
+    next(p)
+    assert threading.active_count() == before + 1
+    p.close()
+    assert threading.active_count() == before
+
+
 def test_host_sharding_partitions_batch():
     cfg = DataConfig(vocab=11, seq_len=8, global_batch=4)
     h0 = batch_at(cfg, 0, host=0, num_hosts=2)
