@@ -13,6 +13,7 @@ import threading
 from typing import Optional
 
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.data.synthetic import DataConfig, batch_at
 
@@ -71,15 +72,20 @@ class Pipeline:
         return self
 
     def __next__(self):
+        # "data.wait": the consumer's wait for a batch, as a host span in
+        # a profiler trace (under a microsecond when none is being taken)
         if self._prefetch <= 0:
-            b = batch_at(self.cfg, self._step, self.host, self.num_hosts)
-            self._step += 1
-            return {k: jnp.asarray(v) for k, v in b.items()}
+            with TraceAnnotation("data.wait"):
+                b = batch_at(self.cfg, self._step, self.host,
+                             self.num_hosts)
+                self._step += 1
+                return {k: jnp.asarray(v) for k, v in b.items()}
         if self._thread is None:
             self._q = queue.Queue(maxsize=self._prefetch)
             self._thread = threading.Thread(
                 target=self._producer, args=(self._step,), daemon=True)
             self._thread.start()
-        s, b = self._q.get()
+        with TraceAnnotation("data.wait"):
+            s, b = self._q.get()
         self._step = s + 1
         return b

@@ -25,6 +25,7 @@ def _group(q: jax.Array, kv_local: int) -> jax.Array:
     return q.reshape(b, s, kv_local, l // kv_local, hd)
 
 
+@jax.named_scope("attention")
 def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       causal: bool,
                       q_positions: Optional[jax.Array] = None,

@@ -295,6 +295,7 @@ class _Flush:
                       for i, v in enumerate(leaves)]
         self.leaf_vals[lo:hi] = leaves
 
+    @jax.named_scope("grad_sync")
     def _flush(self, b: int):
         layout = self.ov.layout
         lo, hi = layout.bucket_leaves(b)
@@ -331,8 +332,9 @@ class _Flush:
             else:
                 for b in range(layout.n_buckets):
                     self._flush(b)
-            self.leaf_vals = bucketing.buckets_to_leaves(
-                self.out_buckets, self.leaf_vals, layout)
+            with jax.named_scope("grad_sync"):
+                self.leaf_vals = bucketing.buckets_to_leaves(
+                    self.out_buckets, self.leaf_vals, layout)
         return _unordered_tree(ov, self.leaf_vals, params_like)
 
     def new_agg(self, agg_states):
@@ -745,11 +747,14 @@ def make_unfused_step(setup, xent_chunk: int = 1024):
         elif setup.agg_cfg.compress_axes or setup.agg_cfg.raw_axes:
             squeezed = tuple(jax.tree.map(lambda x: x[0], st)
                              for st in state["agg"])
-            ordered = _ordered_leaves(ov, grads)
-            buckets = bucketing.leaves_to_buckets(ordered, ov.layout)
-            outs, news = aggregator.aggregate_bucket_list(buckets, squeezed)
-            ordered = bucketing.buckets_to_leaves(outs, ordered, ov.layout)
-            grads = _unordered_tree(ov, ordered, grads)
+            with jax.named_scope("grad_sync"):
+                ordered = _ordered_leaves(ov, grads)
+                buckets = bucketing.leaves_to_buckets(ordered, ov.layout)
+                outs, news = aggregator.aggregate_bucket_list(buckets,
+                                                              squeezed)
+                ordered = bucketing.buckets_to_leaves(outs, ordered,
+                                                      ov.layout)
+                grads = _unordered_tree(ov, ordered, grads)
             new_agg = tuple(jax.tree.map(lambda x: x[None], ns)
                             for ns in news) if squeezed else state["agg"]
         else:

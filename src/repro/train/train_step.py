@@ -523,10 +523,12 @@ def make_update_fn(setup: TrainSetup, layout, ov=None):
         plan = _zero1_plan(setup)
         buckets_of, unbuckets = _zero1_bucket_fns(setup, layout, ov)
 
+        @jax.named_scope("optimizer")
         def update(params, grads, opt_state, lr):
             return zero1_apply(setup, layout, plan, buckets_of, unbuckets,
                                params, grads, opt_state, lr)
     else:
+        @jax.named_scope("optimizer")
         def update(params, grads, opt_state, lr):
             opt = opt_mod.make(setup.opt_cfg.name, setup.opt_cfg,
                                setup.param_specs)
@@ -608,6 +610,7 @@ def make_step(setup: TrainSetup, accum: int = 1, xent_chunk: int = 1024):
         return jax.tree.map(f, grads, setup.param_specs,
                             is_leaf=lambda s: isinstance(s, P))
 
+    @jax.named_scope("grad_sync")
     def aggregate(grads, agg_states):
         """Returns aggregated grads + new compressor states.  The bucket
         loop itself lives in ``GradAggregator.aggregate_bucketed`` (one
@@ -625,6 +628,7 @@ def make_step(setup: TrainSetup, accum: int = 1, xent_chunk: int = 1024):
             return out, news
         return out, agg_states
 
+    @jax.named_scope("grad_sync")
     def aggregate_raw(grads):
         """none-compressor path: one mean over the configured axes, moved
         by the configured CommPlan (auto -> pmean, the historic path)."""

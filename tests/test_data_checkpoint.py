@@ -8,6 +8,7 @@ import tempfile
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.checkpoint import checkpoint as ckpt
 from repro.data.pipeline import Pipeline
@@ -57,6 +58,30 @@ def test_pipeline_close_stops_prefetch_thread():
     assert threading.active_count() == before + 1
     p.close()
     assert threading.active_count() == before
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_pipeline_wait_is_a_trace_span(tmp_path, prefetch):
+    """Each ``next()`` writes one ``data.wait`` host span into a profiler
+    trace: the consumer's wait, where the data layer measures it."""
+    import glob
+
+    from jax.profiler import ProfileData
+    p = Pipeline(DataConfig(vocab=11, seq_len=8, global_batch=2),
+                 prefetch=prefetch)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            next(p)
+    finally:
+        jax.profiler.stop_trace()
+        p.close()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    spans = [ev for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if ev.name == "data.wait"]
+    assert len(spans) == 3
 
 
 def test_host_sharding_partitions_batch():

@@ -12,6 +12,7 @@ bit-exactness oracle is tests/dist/dist_overlap_equivalence.py):
     scan-based step to fp tolerance — different XLA programs).
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -185,6 +186,30 @@ def test_segmented_step_matches_classic_scan_step():
                                                  overlap=False)))
     np.testing.assert_allclose(seg, classic, rtol=5e-4)
     assert seg[-1] < seg[0]        # it trains
+
+
+@pytest.mark.parametrize("overlap_on", [True, False],
+                         ids=["overlapped", "classic"])
+@pytest.mark.parametrize("zero1", [True, False], ids=["zero1", "adamw"])
+def test_step_hlo_carries_layer_scopes(overlap_on, zero1):
+    """The step's lowered HLO names its layers in ``op_name`` metadata:
+    ``attention`` (forward, recompute and backward), ``grad_sync`` (the
+    bucket aggregation) and ``optimizer`` (the update) — what a profiler
+    trace of the compiled step attributes device time by."""
+    mesh = make_local_mesh()
+    batch = next(Pipeline(DataConfig(vocab=64, seq_len=32, global_batch=4),
+                          prefetch=0))
+    cfg = _overlap_cfg(overlap=overlap_on, zero1=zero1,
+                       compression="powersgd", compress_axes="all")
+    setup = ts.build(cfg, mesh)
+    state = jax.eval_shape(lambda k: ts.init_state(setup, k),
+                           jax.random.key(0))
+    step = ts.make_step(setup)(batch)
+    text = step.lower(state, batch, jnp.float32(1e-3)).as_text(
+        dialect="hlo", debug_info=True)
+    parts = {c for name in re.findall(r'op_name="([^"]*)"', text)
+             for c in re.split(r"[/;()]", name)}
+    assert {"attention", "grad_sync", "optimizer"} <= parts
 
 
 # ------------------------------------------------------- ZeRO-1
