@@ -1,9 +1,24 @@
-"""Attention: chunked (flash-style) causal attention + GQA + decode paths.
+"""Attention: causal/full self-attention for training and prefill, GQA,
+and the decode paths.
 
-Pure JAX (lax.scan online-softmax) so the whole train/serve step lowers on
-any backend; the arithmetic is organized exactly as a TPU flash kernel would
-tile it (k/v chunks resident, fp32 running max/denominator), which is also
-what keeps the 32k-prefill activation footprint linear in chunk size.
+Two paths behind ``chunked_attention``, chosen statically while tracing:
+
+- **Pallas flash kernel** (``kernels/flash_attention.py``) for
+  self-attention over the default positions ``0..S-1`` (both position
+  arguments ``None``, ``Sq == Sk``) whose sequence length and head size the
+  kernel tiles (``kernels/ops.flash_attention_fits``), on TPU or with
+  ``force="pallas"``.  Score tiles stay in VMEM, blocks above the causal
+  diagonal are skipped, and each q head reads its kv head through the
+  kernel's index map.  Its Mosaic calls, forward and backward, are
+  labelled ``attention`` in a profile.
+- **jnp online-softmax loop** everywhere else: off TPU, cross-attention
+  (``encdec.py``), explicit ``batch["positions"]``, context-parallel
+  caches, and shapes the kernel does not tile.  Pure JAX (``lax.scan``
+  over k chunks, fp32 running max/denominator), so the step lowers on any
+  backend and the 32k-prefill activation footprint stays linear in chunk
+  size.
+
+``kernels.ops.ATTENTION_PATHS`` counts the calls traced on each path.
 
 Decode supports a context-parallel cache: for long_500k (global_batch=1) the
 KV cache is sharded over the "data" mesh axis along sequence and partial
@@ -15,6 +30,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import ops
 
 NEG_INF = -1e30
 
@@ -32,13 +49,23 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       k_positions: Optional[jax.Array] = None,
                       chunk: int = 1024,
                       q_chunk: int = 2048,
-                      softmax_scale: Optional[float] = None) -> jax.Array:
+                      softmax_scale: Optional[float] = None,
+                      force: Optional[str] = None) -> jax.Array:
     """q: (B, Sq, L, hd); k, v: (B, Sk, KVh, hd) with KVh | L.
 
-    Double-chunked (flash) structure: an outer scan over q blocks bounds
-    every score/probability tensor by (B, q_chunk, heads, chunk) — the
-    O(Sq·Sk) working set never materializes (DESIGN.md §4).
+    Positions ``None`` mean ``0..S-1``.  Self-attention over those takes
+    the Pallas flash kernel where ``ops.flash_attention_fits`` (``force``
+    as in ``kernels/ops.py``).  Otherwise the double-chunked (flash)
+    structure: an outer scan over q blocks bounds every score/probability
+    tensor by (B, q_chunk, heads, chunk) — the O(Sq·Sk) working set never
+    materializes (DESIGN.md §4).
     """
+    if q_positions is None and k_positions is None and \
+            ops.flash_attention_fits(q.shape, k.shape, force=force):
+        ops.ATTENTION_PATHS["pallas"] += 1
+        return ops.flash_attention(q, k, v, causal=causal,
+                                   softmax_scale=softmax_scale)
+    ops.ATTENTION_PATHS["jnp"] += 1
     b, sq, l, hd = q.shape
     if q_positions is None:
         q_positions = jnp.broadcast_to(jnp.arange(sq), (b, sq))

@@ -240,9 +240,7 @@ class Model:
             pe = tf.sp_scatter_embeds(
                 jnp.broadcast_to(pe, (bsz, s_full, cfg.d_model)), ctx)
             x = x + pe.astype(x.dtype)
-        positions = batch.get(
-            "positions", jnp.broadcast_to(jnp.arange(s_full), (bsz, s_full)))
-        aux = Aux(positions=positions,
+        aux = Aux(positions=batch.get("positions"),
                   mrope_positions=batch.get("mrope_positions"))
         return x, aux
 
@@ -370,8 +368,7 @@ class Model:
         x = x + tf.sp_scatter_embeds(
             jnp.broadcast_to(pe, (b, s_full, cfg.d_model)), ctx).astype(
                 x.dtype)
-        aux = Aux(positions=jnp.broadcast_to(jnp.arange(s_full),
-                                             (b, s_full)))
+        aux = Aux()
 
         def body(carry, p_l):
             fn = _remat(partial(encdec.enc_block_apply, aux=aux, ctx=ctx,

@@ -58,8 +58,12 @@ class StepState:
 
 @dataclasses.dataclass(frozen=True)
 class Aux:
-    """Per-step position information (full-sequence, replicated over TP)."""
-    positions: jax.Array                     # (B, S) int32
+    """Per-step position information (full-sequence, replicated over TP).
+
+    ``positions=None`` states, statically, that they are ``0..S-1`` (no
+    ``batch["positions"]``): that is what lets self-attention take the
+    Pallas flash kernel, which has no position input."""
+    positions: Optional[jax.Array] = None    # (B, S) int32
     mrope_positions: Optional[jax.Array] = None   # (3, B, S) int32
 
 
@@ -209,20 +213,26 @@ def attn_apply(params, x, aux: Aux, ctx: ShardCtx, cfg, st: StepState,
     b, s = h.shape[0], h.shape[1]
     if st.decoding:
         positions = st.cur_len[:, None]                   # (B, 1)
+    elif aux.positions is None:
+        positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     else:
         positions = aux.positions[:, :s]
     q, k, v = _project_qkv(params, h, cfg, ctx, lay)
     q, k = _rotate(q, k, aux, cfg, positions)
+    # default positions go to chunked_attention as None (its kernel path);
+    # a context-parallel cache keeps the jnp path
+    attn_pos = None if aux.positions is None and not ctx.cache_seq_axes \
+        else positions
 
     if st.training:
         out = attn_ops.chunked_attention(q, k, v, causal=causal,
-                                         q_positions=positions,
-                                         k_positions=positions)
+                                         q_positions=attn_pos,
+                                         k_positions=attn_pos)
     elif st.mode == "prefill":
         cache = _cache_write(cache, k, v, st, ctx, positions)
         out = attn_ops.chunked_attention(q, k, v, causal=causal,
-                                         q_positions=positions,
-                                         k_positions=positions)
+                                         q_positions=attn_pos,
+                                         k_positions=attn_pos)
     else:  # decode
         cache = _cache_write(cache, k, v, st, ctx, positions)
         s_local = cache["k"].shape[1]

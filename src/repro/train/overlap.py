@@ -350,11 +350,7 @@ class _Flush:
 def _make_aux(batch):
     """Batch-only position info (mirrors Model._embed_in's Aux)."""
     from repro.models.transformer import Aux
-    ref = batch["embeds"] if "embeds" in batch else batch["tokens"]
-    bsz, s_full = ref.shape[0], ref.shape[1]
-    positions = batch.get(
-        "positions", jnp.broadcast_to(jnp.arange(s_full), (bsz, s_full)))
-    return Aux(positions=positions,
+    return Aux(positions=batch.get("positions"),
                mrope_positions=batch.get("mrope_positions"))
 
 
@@ -439,8 +435,7 @@ def _encdec_fns(setup, batch, xent_chunk: int):
         x = x + tf.sp_scatter_embeds(
             jnp.broadcast_to(pe, (b, s_full, cfg.d_model)), ctx).astype(
                 x.dtype)
-        return x, Aux(positions=jnp.broadcast_to(jnp.arange(s_full),
-                                                 (b, s_full)))
+        return x, Aux()
 
     x0, enc_aux = f_enc_in()
 

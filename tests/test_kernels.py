@@ -96,3 +96,102 @@ def test_qsgd_quantize_matches_ref(n, levels):
     np.testing.assert_array_equal(
         kq.quantize(g, norm, levels, key, interpret=True),
         ref.qsgd_quantize(g, norm, levels, key))
+
+
+# ------------------------------------------------------------- flash attention
+# Interpret mode at S=256, hd 64.  The kernel feeds the MXU bf16 operands
+# and accumulates in fp32, as the jnp path does on TPU (its DEFAULT-precision
+# dots round fp32 operands to bf16): both are compared at two bf16 steps
+# (2 x 2^-7) of the reference's largest magnitude.
+BF16_TOL = 2 * 2.0 ** -7
+
+
+def _attn_inputs(h, kvh, s=256, hd=64, b=1):
+    kq, kk, kv, kd = jax.random.split(jax.random.key(h * 10 + kvh), 4)
+    q = jax.random.normal(kq, (b, s, h, hd), jnp.bfloat16)
+    k = jax.random.normal(kk, (b, s, kvh, hd), jnp.bfloat16)
+    v = jax.random.normal(kv, (b, s, kvh, hd), jnp.bfloat16)
+    do = jax.random.normal(kd, (b, s, h, hd), jnp.bfloat16)
+    return q, k, v, do
+
+
+def _close(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=BF16_TOL * np.abs(want).max())
+
+
+@pytest.mark.parametrize("h,kvh,causal,blocks", [
+    (6, 2, True, (128, 128, 128, 128)),
+    (6, 2, False, (128, 128, 128, 128)),
+    (4, 4, True, (128, 128, 128, 128)),
+    (4, 4, False, (128, 128, 128, 128)),
+    (6, 2, True, (256, 128, 128, 256)),
+], ids=["gqa-causal", "gqa-full", "mha-causal", "mha-full",
+        "gqa-causal-uneven-blocks"])
+def test_flash_attention_matches_jnp(h, kvh, causal, blocks):
+    """Output and q/k/v gradients of the kernel against the jnp loop."""
+    from repro.kernels import flash_attention as fa
+    from repro.models.attention import chunked_attention
+    q, k, v, do = _attn_inputs(h, kvh)
+    hm = (0, 2, 1, 3)
+
+    def kernel(q, k, v):
+        return fa.flash_attention(
+            q.transpose(hm), k.transpose(hm), v.transpose(hm),
+            causal=causal, blocks=blocks, interpret=True).transpose(hm)
+
+    def loop(q, k, v):
+        return chunked_attention(q, k, v, causal=causal, force="ref")
+
+    out, vjp = jax.vjp(kernel, q, k, v)
+    want, want_vjp = jax.vjp(loop, q, k, v)
+    _close(out, want)
+    for got_g, want_g in zip(vjp(do), want_vjp(do)):
+        _close(got_g, want_g)
+
+
+def test_flash_attention_dispatch_takes_kernel():
+    """Default positions, Sq == Sk and a tiled length: the kernel path,
+    counted in ops.ATTENTION_PATHS, with the kernel's own result."""
+    from repro.kernels import flash_attention as fa
+    from repro.kernels import ops
+    from repro.models.attention import chunked_attention
+    q, k, v, _ = _attn_inputs(6, 2)
+    before = ops.ATTENTION_PATHS.copy()
+    out = chunked_attention(q, k, v, causal=True, force="pallas")
+    assert ops.ATTENTION_PATHS["pallas"] == before["pallas"] + 1
+    assert ops.ATTENTION_PATHS["jnp"] == before["jnp"]
+    hm = (0, 2, 1, 3)
+    direct = fa.flash_attention(q.transpose(hm), k.transpose(hm),
+                                v.transpose(hm), causal=True,
+                                interpret=True).transpose(hm)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(direct, np.float32))
+
+
+@pytest.mark.parametrize("case", ["ragged-length", "cross-attention",
+                                  "explicit-positions"])
+def test_flash_attention_dispatch_falls_back(case):
+    """What the kernel does not take stays on the jnp path, bit for bit
+    the result of the jnp path itself."""
+    from repro.kernels import ops
+    from repro.models.attention import chunked_attention
+    if case == "ragged-length":
+        q, k, v, _ = _attn_inputs(6, 2, s=200)
+        kw = dict(causal=True)
+    elif case == "cross-attention":
+        q = _attn_inputs(6, 2, s=128)[0]
+        _, k, v, _ = _attn_inputs(6, 2, s=256)
+        kw = dict(causal=False)
+    else:
+        q, k, v, _ = _attn_inputs(6, 2)
+        pos = jnp.broadcast_to(jnp.arange(256) * 2, (1, 256))
+        kw = dict(causal=True, q_positions=pos, k_positions=pos)
+    before = ops.ATTENTION_PATHS.copy()
+    out = chunked_attention(q, k, v, force="pallas", **kw)
+    assert ops.ATTENTION_PATHS["jnp"] == before["jnp"] + 1
+    assert ops.ATTENTION_PATHS["pallas"] == before["pallas"]
+    want = chunked_attention(q, k, v, force="ref", **kw)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(want, np.float32))

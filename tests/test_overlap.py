@@ -212,6 +212,37 @@ def test_step_hlo_carries_layer_scopes(overlap_on, zero1):
     assert {"attention", "grad_sync", "optimizer"} <= parts
 
 
+@pytest.mark.parametrize("positions", ["default", "explicit"])
+@pytest.mark.parametrize("overlap_on", [True, False],
+                         ids=["overlapped", "classic"])
+def test_step_attention_path_follows_positions(overlap_on, positions,
+                                               monkeypatch):
+    """Tracing the train step with the kernels forced: a batch without
+    ``positions`` runs every attention on the Pallas flash kernel, one with
+    explicit positions every attention on the jnp loop
+    (``ops.ATTENTION_PATHS``)."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "FORCE", "pallas")
+    mesh = make_local_mesh()
+    batch = next(Pipeline(DataConfig(vocab=64, seq_len=128, global_batch=2),
+                          prefetch=0))
+    assert "positions" not in batch
+    if positions == "explicit":
+        batch = dict(batch, positions=jnp.broadcast_to(
+            jnp.arange(128, dtype=jnp.int32), (2, 128)))
+    # head size 64, which the kernel tiles
+    cfg = dataclasses.replace(_overlap_cfg(overlap=overlap_on), head_dim=64)
+    setup = ts.build(cfg, mesh)
+    state = jax.eval_shape(lambda k: ts.init_state(setup, k),
+                           jax.random.key(0))
+    before = ops.ATTENTION_PATHS.copy()
+    ts.make_step(setup)(batch).lower(state, batch, jnp.float32(1e-3))
+    traced = ops.ATTENTION_PATHS - before
+    taken, other = ("pallas", "jnp") if positions == "default" \
+        else ("jnp", "pallas")
+    assert traced[taken] >= 1 and traced[other] == 0, traced
+
+
 # ------------------------------------------------------- ZeRO-1
 def test_zero1_owner_plan_covers_buckets():
     from repro.core import bucketing

@@ -65,3 +65,37 @@ def test_kernel_compiles_for_v5e(name, one_chip):
             for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+#: the benchmark cells' attention: (batch per chip, q heads, kv heads) at
+#: seq 2048, head size 64 — smollm2-360m (GQA 15/5) and smollm2-1.7b (MHA)
+ATTENTION = {"smollm2-360m": (4, 15, 5), "smollm2-1.7b": (2, 32, 32)}
+
+
+@pytest.mark.parametrize("cell", sorted(ATTENTION))
+def test_flash_attention_compiles_for_v5e(cell, one_chip):
+    """Forward, dK/dV and dQ kernels under remat, as the train step runs
+    them: the compiler accepts their tiling and VMEM, and all are Mosaic
+    calls named by the ``attention`` scope."""
+    import re
+
+    from repro.kernels import flash_attention as fa
+    b, h, kvh = ATTENTION[cell]
+    q = jax.ShapeDtypeStruct((b, h, 2048, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, kvh, 2048, 64), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def grads(q, k, v):
+        def loss(q, k, v):
+            o = jax.checkpoint(lambda *a: fa.flash_attention(
+                *a, causal=True))(q, k, v)
+            return jnp.sum(o.astype(jnp.float32) ** 2)
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(grads).lower(q, kv, kv).compile().as_text()
+    calls = [ln.split(" = ", 1)[0].strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " = " in ln]
+    # forward, its recompute, dK/dV, dQ
+    assert len(calls) == 4, calls
+    assert all(re.fullmatch(r"%attention\.\d+", c) for c in calls), calls
