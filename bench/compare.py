@@ -48,13 +48,14 @@ def _worst(prog: dict, ref: dict, keep: list, scale: dict | None = None
     return worst, where
 
 
-def leaf_diff_norms(a: dict, b: dict) -> dict:
-    """{label: norm of a - b} per leaf (per layer for stacked leaves), of
-    two gradients held as host arrays by path."""
+def leaf_diff_norms(a: dict, b: dict, labels) -> dict:
+    """{label: norm of a - b} per leaf of two gradients held as host arrays
+    by path: per layer for a leaf that ``labels`` holds by layer
+    (``path#layer``), whole otherwise."""
     out = {}
     for p, x in a.items():
         d = x.astype(np.float64) - b[p]
-        if p.startswith("blocks/"):
+        if p not in labels:
             n = np.sqrt(np.sum(d * d, axis=tuple(range(1, d.ndim))))
             out.update({f"{p}#{l}": float(n[l]) for l in range(d.shape[0])})
         else:
@@ -77,7 +78,7 @@ def numbers(prog: dict, ref: dict) -> dict:
            "leaves_compared": len(keep), "leaves": len(ref["grad"])}
     if "grad_vec" in prog and "grad_vec" in ref:
         out["grad_err"], out["grad_err_at"] = _worst(
-            leaf_diff_norms(prog["grad_vec"], ref["grad_vec"]),
+            leaf_diff_norms(prog["grad_vec"], ref["grad_vec"], ref["grad"]),
             {k: 0.0 for k in ref["grad"]}, keep, scale=ref["grad"])
     return out
 

@@ -2,9 +2,9 @@
 is (synchronous SGD)."""
 
 
-def init(key, cfg: dict, workload: dict):
+def init(key, cfg: dict, workload: dict, arch):
     return ()
 
 
-def apply(grads: dict, state, cfg: dict, workload: dict):
+def apply(grads: dict, state, cfg: dict, workload: dict, arch):
     return grads, state

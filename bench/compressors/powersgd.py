@@ -20,21 +20,21 @@ from bench import flops, weights
 from bench.reference import buckets, map_buckets
 
 
-def _shapes(cfg: dict, workload: dict) -> list[tuple[int, int]]:
+def _shapes(cfg: dict, workload: dict, arch) -> list[tuple[int, int]]:
     return [flops.powersgd_matrix_shape(sum(leaf[2] for leaf in b))
-            for b in buckets(cfg, workload)]
+            for b in buckets(cfg, workload, arch)]
 
 
-def init(key, cfg: dict, workload: dict):
+def init(key, cfg: dict, workload: dict, arch):
     rank = workload["plan"]["powersgd_rank"]
     return tuple(
         (weights.powersgd_q(key, i, cols, rank),
          jnp.zeros((sum(leaf[2] for leaf in bkt),), jnp.float32))
-        for i, (bkt, (_, cols)) in enumerate(zip(buckets(cfg, workload),
-                                                 _shapes(cfg, workload))))
+        for i, (bkt, (_, cols)) in enumerate(zip(
+            buckets(cfg, workload, arch), _shapes(cfg, workload, arch))))
 
 
-def apply(grads: dict, state, cfg: dict, workload: dict):
+def apply(grads: dict, state, cfg: dict, workload: dict, arch):
     hp = jax.lax.Precision.HIGHEST
 
     def one(flat, st):
@@ -48,7 +48,7 @@ def apply(grads: dict, state, cfg: dict, workload: dict):
         m_hat = jnp.dot(p_hat, q_new.T, precision=hp).reshape(-1)[:n]
         return m_hat, (q_new, mflat - m_hat)
 
-    return map_buckets(one, grads, state, cfg, workload)
+    return map_buckets(one, grads, state, cfg, workload, arch)
 
 
 def seed_program(key, agg, workload: dict):

@@ -14,14 +14,14 @@ import jax.numpy as jnp
 from bench.reference import buckets, map_buckets
 
 
-def init(key, cfg: dict, workload: dict):
+def init(key, cfg: dict, workload: dict, arch):
     if workload["chips"] != 1:
         raise ValueError("the signSGD reference holds for one replica")
     return tuple(jnp.zeros((sum(leaf[2] for leaf in b),), jnp.float32)
-                 for b in buckets(cfg, workload))
+                 for b in buckets(cfg, workload, arch))
 
 
-def apply(grads: dict, state, cfg: dict, workload: dict):
+def apply(grads: dict, state, cfg: dict, workload: dict, arch):
     feedback = workload["plan"].get("error_feedback", True)
 
     def one(flat, err):
@@ -29,4 +29,4 @@ def apply(grads: dict, state, cfg: dict, workload: dict):
         out = jnp.where(g >= 0, 1.0, -1.0) * jnp.mean(jnp.abs(g))
         return out, (g - out if feedback else err)
 
-    return map_buckets(one, grads, state, cfg, workload)
+    return map_buckets(one, grads, state, cfg, workload, arch)

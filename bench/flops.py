@@ -2,41 +2,12 @@
 
 Kept with the benchmark so that no change to the program can change them.
 Every count is of what the algorithm needs, from the configuration's shapes,
-whatever implements it.
+whatever implements it.  A model's FLOPs per token are its architecture
+module's (``train_flops_per_token`` in ``bench/models/<arch>.py``).
 """
 from __future__ import annotations
 
 import math
-
-
-def matmul_params(cfg: dict) -> int:
-    """Parameters that take part in a matrix multiplication, per token:
-    every layer's projections and the LM head (the tied embedding counts
-    once, as the head; the embedding lookup is a gather)."""
-    d, hd = cfg["d_model"], cfg["head_dim"]
-    q = cfg["n_heads"] * hd
-    kv = cfg["n_kv_heads"] * hd
-    per_layer = d * q + 2 * d * kv + q * d + 3 * d * cfg["d_ff"]
-    return cfg["n_layers"] * per_layer + cfg["vocab"] * d
-
-
-def param_count(cfg: dict) -> int:
-    """Every trained parameter: the matmul parameters, two RMSNorm scales
-    per layer and the final norm (plus an untied head where there is one)."""
-    n = matmul_params(cfg) + (2 * cfg["n_layers"] + 1) * cfg["d_model"]
-    if not cfg["tie_embeddings"]:
-        n += cfg["vocab"] * cfg["d_model"]
-    return n
-
-
-def train_flops_per_token(cfg: dict, seq: int) -> float:
-    """Model FLOPs per trained token: 6 x matmul parameters (forward 2,
-    backward 4) plus causal attention, 6 x layers x seq x heads x head_dim
-    (QK^T and PV, forward and backward, half of the square kept by the
-    causal mask).  Recomputation under remat is not counted."""
-    attn = 6 * cfg["n_layers"] * seq * cfg["n_heads"] * cfg["head_dim"]
-    return 6.0 * matmul_params(cfg) + attn
-
 
 LANES = 128
 

@@ -10,8 +10,12 @@ Order of a run:
     state filled with the seed's weights, the feed started;
  4. three steps through the timed call and feed: the cell's one shape
     compiles and warms up, and the readings for ``correct`` are taken;
- 5. a window of ``--seconds`` in which every step ends in
-    ``block_until_ready`` (with ``--trace 1`` under the profiler);
+ 5. a window of ``--seconds`` in which steps are dispatched
+    ``AHEAD_S`` seconds ahead of the one waited for (a host that stands
+    still meanwhile leaves the chip fed), each step's loss read once the
+    step has ended; when the time is up nothing more is sent, all that
+    was sent is waited for, and the window closes after that wait (with
+    ``--trace 1`` under the profiler);
  6. peak memory read, the program's state freed, the reference run over
     the seed's first three batches as ``bench/data.py`` makes them, and
     the result printed: the numbers compared,
@@ -31,6 +35,8 @@ import time
 
 CHECK_STEPS = 3
 EXIT_NO_CHIP = 2
+#: seconds of steps kept dispatched ahead of the step waited for
+AHEAD_S = 6.0
 
 
 class NoChip(Exception):
@@ -105,8 +111,8 @@ def first_steps(prog, key, step, feed, batch, lr, keep_grad: bool,
             batch = batch_fn(batch)
         state, metrics = step(state, batch, lr)
         readings["losses"].append(float(metrics["loss"]))
+        times[f"step{i + 1}"] = time.perf_counter()
         if i == 0:
-            times["step1"] = time.perf_counter()
             m = prog.grad_tree(state)
             readings["grad"] = {k: float(v) * scale
                                 for k, v in grad_norms(m).items()}
@@ -118,6 +124,59 @@ def first_steps(prog, key, step, feed, batch, lr, keep_grad: bool,
     jax.block_until_ready(state)
     times["end"] = time.perf_counter()
     return state, readings
+
+
+def ahead(times: dict) -> int:
+    """Steps to keep dispatched ahead: ``AHEAD_S`` over the last checked
+    step's time (each checked step ends in reading its loss)."""
+    last = times[f"step{CHECK_STEPS}"] - times[f"step{CHECK_STEPS - 1}"]
+    return max(1, math.ceil(AHEAD_S / max(last, 1e-3)))
+
+
+def window(step, state, feed, lr, seconds: float, depth: int):
+    """Steps from ``state`` for ``seconds``, ``depth`` of them dispatched
+    ahead of the one waited for; then nothing more is sent and all that
+    was sent is waited for.  Returns ``(state, metrics, out)``: ``out``
+    has ``window_s`` (first dispatch to the end of the last step),
+    ``step_s`` (time between the ends of successive steps, as the host
+    saw them; the first from the window's start), ``wait_s`` (each
+    step's wait for its batch) and ``losses``."""
+    import collections
+
+    import jax
+
+    annotate = jax.profiler.TraceAnnotation
+    step_s, wait_s, losses = [], [], []
+    pending = collections.deque()
+    t_win = last = time.perf_counter()
+    metrics = None
+
+    def retire():
+        nonlocal last
+        with annotate("bench.block"):
+            m = pending.popleft()
+            jax.block_until_ready(m)
+        now = time.perf_counter()
+        step_s.append(now - last)
+        last = now
+        losses.append(float(m["loss"]))
+
+    while time.perf_counter() - t_win < seconds:
+        t0 = time.perf_counter()
+        with annotate("bench.input_wait"):
+            batch = next(feed)
+        wait_s.append(time.perf_counter() - t0)
+        with annotate("bench.dispatch"):
+            state, metrics = step(state, batch, lr)
+        pending.append(metrics)
+        if len(pending) > depth:
+            retire()
+    while pending:
+        retire()
+    jax.block_until_ready(state)
+    out = {"window_s": time.perf_counter() - t_win, "step_s": step_s,
+           "wait_s": wait_s, "losses": losses}
+    return state, metrics, out
 
 
 def run(argv_ns, t_start: float, spec, *, require_tpu: bool = True,
@@ -142,13 +201,13 @@ def run(argv_ns, t_start: float, spec, *, require_tpu: bool = True,
     peak = spec.peak(device["kind"]) if require_tpu else None
     compiles = _CompileCounter(jax)
 
-    from bench import compare, data, flops, weights
+    from bench import compare, data, weights
 
     seed = argv_ns.seed
     key = weights.seed_key(seed)
     limits = w["limits"]
-    compressor = spec.compressor(w)
-    prog = prog_mod.Program(cfg, w, compressor)
+    arch, compressor = spec.arch(cfg), spec.compressor(w)
+    prog = prog_mod.Program(cfg, w, arch, compressor)
     feed = prog.feed(seed)
     batch = next(feed)
     step = prog.make_step(batch)
@@ -165,26 +224,12 @@ def run(argv_ns, t_start: float, spec, *, require_tpu: bool = True,
         trace_dir = os.path.join(spec.root, ".bench_trace", w["name"])
         shutil.rmtree(trace_dir, ignore_errors=True)
         jax.profiler.start_trace(trace_dir)
-    annotate = jax.profiler.TraceAnnotation
-    step_s, wait_s, win_losses = [], [], []
+    depth = ahead(readings["times"])
     compiles.active = True
-    t_win = time.perf_counter()
-    setup_s = t_win - t_start
-    now = t_win
-    while now - t_win < argv_ns.seconds:
-        t0 = time.perf_counter()
-        with annotate("bench.input_wait"):
-            batch = next(feed)
-        t1 = time.perf_counter()
-        with annotate("bench.dispatch"):
-            state, metrics = step(state, batch, lr)
-        with annotate("bench.block"):
-            jax.block_until_ready((state, metrics))
-        now = time.perf_counter()
-        step_s.append(now - t0)
-        wait_s.append(t1 - t0)
-        win_losses.append(float(metrics["loss"]))
-    window_s = now - t_win
+    setup_s = time.perf_counter() - t_start
+    state, metrics, win = window(step, state, feed, lr, argv_ns.seconds,
+                                 depth)
+    window_s, step_s, wait_s = win["window_s"], win["step_s"], win["wait_s"]
     compiles.active = False
     if trace_dir:
         jax.profiler.stop_trace()
@@ -196,7 +241,7 @@ def run(argv_ns, t_start: float, spec, *, require_tpu: bool = True,
     # ---- the comparison with the reference ---------------------------
     from bench.reference import Reference
     t_ref = time.perf_counter()
-    ref = Reference(cfg, w, compressor).run(
+    ref = Reference(cfg, w, arch, compressor).run(
         key, data.batches(cfg, w, prog.global_batch, seed, CHECK_STEPS),
         keep_grad="grad_err" in limits)
     nums = compare.numbers(readings, ref)
@@ -204,7 +249,7 @@ def run(argv_ns, t_start: float, spec, *, require_tpu: bool = True,
     t_ref = time.perf_counter() - t_ref
 
     n = len(step_s)
-    failed = sum(1 for x in win_losses if not math.isfinite(x))
+    failed = sum(1 for x in win["losses"] if not math.isfinite(x))
     tm = readings["times"]
     _err(f"[bench] {w['name']} seed={seed} device={device} "
          f"setup={setup_s:.3f}s: jax_init={t_jax - t_start:.3f}s "
@@ -212,8 +257,9 @@ def run(argv_ns, t_start: float, spec, *, require_tpu: bool = True,
          f"state={tm['state'] - tm['start']:.3f}s "
          f"step1_compile_or_load={tm['step1'] - tm['state']:.3f}s "
          f"steps2_3_and_readers={tm['end'] - tm['step1']:.3f}s "
-         f"to_window={t_win - tm['end']:.3f}s; "
-         f"window={window_s:.3f}s steps={n} (p90 over {n} samples) "
+         f"to_window={t_start + setup_s - tm['end']:.3f}s; "
+         f"window={window_s:.3f}s steps={n} ahead={depth} "
+         f"(p90 over {n} samples) "
          f"compiles_in_window={compiles.count} reference={t_ref:.3f}s")
     _err(f"[bench] losses program={readings['losses']} "
          f"reference={ref['losses']}")
@@ -228,8 +274,9 @@ def run(argv_ns, t_start: float, spec, *, require_tpu: bool = True,
     record = {"steps": n, "window_s": window_s, "step_s": step_s,
               "input_wait_s": wait_s, "tokens_per_step": tokens_per_step,
               "chips": device["count"], "peak": peak, "cfg": cfg,
-              "workload": w,
-              "flops_per_token": flops.train_flops_per_token(cfg, w["seq"])}
+              "workload": w, "arch": arch,
+              "flops_per_token": arch.train_flops_per_token(cfg,
+                                                            w["seq"])}
     if argv_ns.trace:
         from bench import trace_reduce
         summary = trace_reduce.reduce_dir(trace_dir)

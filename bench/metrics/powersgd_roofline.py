@@ -1,6 +1,8 @@
 """PowerSGD's share of its roofline: the least time the chip could take
 for one step's PowerSGD matmuls (work counts in ``bench/flops.py``) over
-the Pallas kernels' device time per step.  Each bucket's least time
+the device time per step of the Pallas kernels labelled by the program's
+``grad_sync`` scope (``compress_kernel_ms``'s; the flash-attention calls,
+labelled ``attention``, are not counted).  Each bucket's least time
 is the larger of its FLOPs over peak and its bytes over HBM bandwidth;
 the least times add up.  Which bound sets them goes to stderr (at rank 4
 every bucket is bound by HBM)."""
@@ -16,7 +18,7 @@ def least_seconds(record: dict) -> tuple[float, str]:
     w = record["workload"]
     rank = w["plan"]["powersgd_rank"]
     t, bounds = 0.0, set()
-    for bkt in reference.buckets(record["cfg"], w):
+    for bkt in reference.buckets(record["cfg"], w, record["arch"]):
         rows, cols = flops.powersgd_matrix_shape(sum(l[2] for l in bkt))
         least, bound = flops.roofline_seconds(
             *flops.powersgd_work(rows, cols, rank), record["peak"])
@@ -26,7 +28,7 @@ def least_seconds(record: dict) -> tuple[float, str]:
 
 
 def read(trace: dict, record: dict):
-    t = sum(trace["kernel_s"].values())
+    t = trace["kernel_s"].get("grad_sync", 0.0)
     if t <= 0 or not record["steps"] or \
             record["workload"]["plan"].get("compression") != "powersgd":
         return None

@@ -50,11 +50,13 @@ def _path_str(path) -> str:
 class Program:
     """One cell's compiled train step, its state and its data feed.
 
-    ``compressor`` is the cell's module from ``bench/compressors``; its
-    ``seed_program``, where it has one, puts the seed's starting state
-    into the program's compressor state."""
+    ``arch`` is the configuration's module from ``bench/models``: the
+    program's configuration and the leaves' layout.  ``compressor`` is the
+    cell's module from ``bench/compressors``; its ``seed_program``, where
+    it has one, puts the seed's starting state into the program's
+    compressor state."""
 
-    def __init__(self, cfg: dict, workload: dict, compressor=None):
+    def __init__(self, cfg: dict, workload: dict, arch, compressor=None):
         import jax
 
         from bench import weights
@@ -63,15 +65,11 @@ class Program:
         from repro.train import train_step as ts
         from repro.train.optimizer import OptConfig
 
-        arch_keys = ("n_layers", "d_model", "n_heads", "n_kv_heads", "d_ff",
-                     "vocab", "head_dim", "rope", "rope_theta",
-                     "tie_embeddings", "norm_eps")
-        arch = ArchConfig(name=cfg["name"], family=cfg["family"],
-                          plan=ParallelPlan(**workload["plan"]),
-                          **{k: cfg[k] for k in arch_keys})
+        config = ArchConfig(plan=ParallelPlan(**workload["plan"]),
+                            **arch.arch_config(cfg))
         self.cfg, self.workload = cfg, workload
-        self.compressor = compressor
-        self.setup = ts.build(arch, make_local_mesh(),
+        self.arch, self.compressor = arch, compressor
+        self.setup = ts.build(config, make_local_mesh(),
                               opt_cfg=OptConfig(name="adamw",
                                                 **workload["optimizer"]))
         self.n_dev = self.setup.mesh.devices.size
@@ -110,14 +108,14 @@ class Program:
         from repro.train import train_step as ts
 
         setup = self.setup
-        if self.shapes != weights.shapes(self.cfg):
+        if self.shapes != self.arch.shapes(self.cfg):
             raise ValueError(f"program parameters {self.shapes} differ from "
                              f"the benchmark's layout")
-        paths, treedef, cfg, dtype = (self.paths, self._treedef, self.cfg,
-                                      self.param_dtype)
+        paths, treedef, cfg, dtype, arch = (
+            self.paths, self._treedef, self.cfg, self.param_dtype, self.arch)
 
         def make_params(k):
-            w = weights.make(k, cfg, dtype)
+            w = weights.make(k, cfg, dtype, arch)
             return jax.tree_util.tree_unflatten(treedef,
                                                 [w[p] for p in paths])
 
@@ -150,7 +148,10 @@ class Program:
         import jax
         import numpy as np
 
+        from bench.reference import stacked
         from repro.train import overlap
+
+        stacks = self.arch.STACKS
 
         def per_layer(p):
             return [f"{p}#{l}" for l in range(self.shapes[p][0])]
@@ -158,7 +159,7 @@ class Program:
         if not self.setup.overlap:
             out = []
             for p in self.paths:
-                if p.startswith("blocks/"):
+                if stacked(p, stacks):
                     size = int(np.prod(self.shapes[p][1:]))
                     out += [(lab, size) for lab in per_layer(p)]
                 else:
@@ -166,7 +167,7 @@ class Program:
             return out
         ov = overlap.build_layout(self.setup)
         tree = jax.tree_util.tree_unflatten(self._treedef, [
-            np.array(per_layer(p)) if p.startswith("blocks/") else p
+            np.array(per_layer(p)) if stacked(p, stacks) else p
             for p in self.paths])
         return list(zip((str(x) for x in overlap._ordered_leaves(ov, tree)),
                         ov.layout.leaf_sizes))
@@ -221,22 +222,23 @@ class Program:
 
         from bench import weights
         from bench.reference import leaf_norms
-        cfg, dtype = self.cfg, self.param_dtype
+        cfg, dtype, arch = self.cfg, self.param_dtype, self.arch
 
         if not self.setup.zero1:
             def norms(tree, key=None):
                 tree = {p: x.astype(jnp.float32) for p, x in tree.items()}
                 if minus_weights:
-                    w = weights.make(key, cfg, dtype)
+                    w = weights.make(key, cfg, dtype, arch)
                     tree = {p: x - w[p].astype(jnp.float32)
                             for p, x in tree.items()}
-                return leaf_norms(tree)
+                return leaf_norms(tree, arch.STACKS)
             return jax.jit(norms)
 
         segs = self._segments()
 
         def shard_norms(shards, key=None):
-            w = weights.make(key, cfg, dtype) if minus_weights else None
+            w = weights.make(key, cfg, dtype, arch) if minus_weights \
+                else None
             acc: dict = {}
             for label, r, lo, hi, leaf_lo in segs:
                 x = shards[r, lo:hi]

@@ -74,11 +74,11 @@ def main(argv=None) -> int:
             out.write(line + "\n")
             out.flush()
 
-    compressor = sp.compressor(w)
-    prog = program.Program(cfg, w, compressor)
+    arch, compressor = sp.arch(cfg), sp.compressor(w)
+    prog = program.Program(cfg, w, arch, compressor)
     lr = jnp.float32(w["lr"])
-    ref = Reference(cfg, w, compressor)
-    ctl = Reference(cfg, w, compressor, precision="fp8") \
+    ref = Reference(cfg, w, arch, compressor)
+    ctl = Reference(cfg, w, arch, compressor, precision="fp8") \
         if args.control_seeds else None
     step = None
 

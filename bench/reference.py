@@ -1,35 +1,36 @@
-"""Plain float32 reference of a data-parallel training step of a
-Llama-style decoder, for the comparison that decides ``correct``.
+"""Plain float32 reference of a data-parallel training step, for the
+comparison that decides ``correct``.
 
-It imports nothing of the program.  Weights come from ``bench.weights``
-and token batches from ``bench.data``, both made from the seed.  Matrix
-products run at ``precision="highest"`` (true float32 on a TPU);
-``precision="fp8"`` is the control: every matrix product of the model,
-forward and backward, takes operands rounded to float8 e4m3 with a
-per-tensor power-of-two scale, the step below the configuration's
-bfloat16.
+It imports nothing of the program.  The model is the configuration's
+architecture module, ``bench/models/<arch>.py`` (found by name,
+``Spec.arch``): its leaves (``shapes``), its stacks of layers (``STACKS``)
+and its loss (``loss_sum``).  Weights come from ``bench.weights`` and token
+batches from ``bench.data``, both made from the seed.  Matrix products run
+at ``precision="highest"`` (true float32 on a TPU); ``precision="fp8"`` is
+the control: every matrix product of the model, forward and backward,
+takes operands rounded to float8 e4m3 with a per-tensor power-of-two
+scale, the step below the configuration's bfloat16.
 
 The step it follows:
 
 1. loss = mean next-token cross-entropy over the global batch; gradient of
-   it (RMSNorm, RoPE rotate-half, grouped-query causal attention, SwiGLU,
-   tied head when the configuration ties it);
+   it;
 2. the cell's compressor, ``bench/compressors/<compression>.py`` (found
    by name, ``Spec.compressor``), on the DDP buckets: whole leaves in the
-   order backward completes them (last layer first, a layer's leaves by
-   name, then the remaining leaves by name), a bucket closing once it
-   holds ``bucket_mb`` of gradient in the weights' dtype;
+   order backward completes them (each stack in ``STACKS`` order, last
+   layer first, a layer's leaves by name, then the remaining leaves by
+   name), a bucket closing once it holds ``bucket_mb`` of gradient in the
+   weights' dtype;
 3. clipping to ``grad_clip`` by global norm;
 4. AdamW with decoupled weight decay, bias-corrected moments.
 
-A compressor module has ``init(key, cfg, workload) -> state`` and
-``apply(grads, state, cfg, workload) -> (grads, state)`` over the mean
-gradient by path, and may have ``seed_program(key, agg, workload)``, which
-puts the same starting state into the program's.
+A compressor module has ``init(key, cfg, workload, arch) -> state`` and
+``apply(grads, state, cfg, workload, arch) -> (grads, state)`` over the
+mean gradient by path, and may have ``seed_program(key, agg, workload)``,
+which puts the same starting state into the program's.
 
-Memory: layers run under ``jax.checkpoint`` and the head's logits in
-chunks, so activations stay small; the batch is split over every device
-and the optimizer moments over their last axis.
+Memory: the batch is split over every device and the optimizer moments
+over their last axis; the architecture module keeps activations small.
 """
 from __future__ import annotations
 
@@ -42,12 +43,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from bench import weights
-
-LAYER_NAMES = ("attn/wk/w", "attn/wo/w", "attn/wq/w", "attn/wv/w",
-               "ln1/scale", "ln2/scale", "mlp/down/w", "mlp/gate/w",
-               "mlp/up/w")
-LOGIT_CHUNK = 512
-
 
 # ------------------------------------------------------------ precision
 def _pow2_scale(x):
@@ -88,95 +83,35 @@ def _einsum_fn(precision: str):
     raise ValueError(f"precision {precision!r}")
 
 
-# ---------------------------------------------------------------- model
-def _rmsnorm(x, scale, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
-
-
-def _rope(x, theta):
-    """Rotate-half RoPE over (B, S, heads, hd) at positions 0..S-1."""
-    s, hd = x.shape[1], x.shape[-1]
-    half = hd // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv      # (S, half)
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def _layer(cfg, es, x, p):
-    b, s, _ = x.shape
-    H, KV, hd = cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"]
-    h = _rmsnorm(x, p["ln1/scale"], cfg["norm_eps"])
-    q = es("bsd,de->bse", h, p["attn/wq/w"]).reshape(b, s, H, hd)
-    k = es("bsd,de->bse", h, p["attn/wk/w"]).reshape(b, s, KV, hd)
-    v = es("bsd,de->bse", h, p["attn/wv/w"]).reshape(b, s, KV, hd)
-    q, k = _rope(q, cfg["rope_theta"]), _rope(k, cfg["rope_theta"])
-    q = q.reshape(b, s, KV, H // KV, hd)
-    sc = es("bqkgd,bskd->bkgqs", q, k) * hd ** -0.5
-    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
-    sc = jnp.where(causal, sc, -jnp.inf)
-    pr = jax.nn.softmax(sc, axis=-1)
-    o = es("bkgqs,bskd->bqkgd", pr, v).reshape(b, s, H * hd)
-    x = x + es("bse,ed->bsd", o, p["attn/wo/w"])
-    h = _rmsnorm(x, p["ln2/scale"], cfg["norm_eps"])
-    g = es("bsd,df->bsf", h, p["mlp/gate/w"])
-    u = es("bsd,df->bsf", h, p["mlp/up/w"])
-    return x + es("bsf,fd->bsd", jax.nn.silu(g) * u, p["mlp/down/w"])
-
-
-def loss_sum(params: dict, tokens, labels, cfg: dict, precision: str):
-    """Summed next-token cross-entropy over the batch (labels < 0 masked)."""
-    es = _einsum_fn(precision)
-    x = params["embed/table"][tokens]
-    stacked = {n: params["blocks/" + n] for n in LAYER_NAMES}
-    x, _ = jax.lax.scan(
-        jax.checkpoint(lambda c, p: (_layer(cfg, es, c, p), None)),
-        x, stacked)
-    x = _rmsnorm(x, params["final_norm/scale"], cfg["norm_eps"])
-    head = params["embed/table" if cfg["tie_embeddings"] else
-                   "unembed/table"]
-    b, s, d = x.shape
-    c = min(LOGIT_CHUNK, s)
-    xs = x.reshape(b, s // c, c, d).swapaxes(0, 1)
-    ls = labels.reshape(b, s // c, c).swapaxes(0, 1)
-
-    @jax.checkpoint
-    def chunk(xc, lc):
-        logits = es("bcd,vd->bcv", xc, head)
-        lse = jax.nn.logsumexp(logits, -1)
-        gold = jnp.take_along_axis(logits, jnp.maximum(lc, 0)[..., None],
-                                   -1)[..., 0]
-        return jnp.sum((lse - gold) * (lc >= 0))
-
-    tot, _ = jax.lax.scan(lambda t, xl: (t + chunk(*xl), None),
-                          jnp.float32(0), (xs, ls))
-    return tot
-
-
 # -------------------------------------------------------------- buckets
-def ordered_leaves(cfg: dict) -> list[tuple[str, int | None, int]]:
+def stacked(path: str, stacks) -> bool:
+    """Whether leaf ``path`` belongs to one of ``stacks`` (its layers on
+    the leading axis)."""
+    return path.split("/", 1)[0] in stacks
+
+
+def ordered_leaves(cfg: dict, arch) -> list[tuple[str, int | None, int]]:
     """(path, layer, size) in the order backward completes them."""
-    shapes = weights.shapes(cfg)
+    shapes = arch.shapes(cfg)
     out = []
-    for l in reversed(range(cfg["n_layers"])):
-        for n in LAYER_NAMES:
-            out.append(("blocks/" + n, l,
-                        math.prod(shapes["blocks/" + n][1:])))
+    for stack in arch.STACKS:
+        names = sorted(p for p in shapes if stacked(p, (stack,)))
+        for l in reversed(range(shapes[names[0]][0])):
+            out += [(p, l, math.prod(shapes[p][1:])) for p in names]
     for p in sorted(shapes):
-        if not p.startswith("blocks/"):
+        if not stacked(p, arch.STACKS):
             out.append((p, None, math.prod(shapes[p])))
     return out
 
 
-def buckets(cfg: dict, workload: dict) -> list[list[tuple]]:
+def buckets(cfg: dict, workload: dict, arch) -> list[list[tuple]]:
     """Leaf runs of the DDP buckets (a bucket closes once it holds at
     least ``bucket_mb`` of gradient; a large leaf joins the open bucket
     whole)."""
     itemsize = jnp.dtype(weights.param_dtype(workload["plan"])).itemsize
     target = max(1, int(workload["plan"]["bucket_mb"] * 2**20) // itemsize)
     out, cur, acc = [], [], 0
-    for leaf in ordered_leaves(cfg):
+    for leaf in ordered_leaves(cfg, arch):
         if acc >= target:
             out.append(cur)
             cur, acc = [], 0
@@ -186,13 +121,13 @@ def buckets(cfg: dict, workload: dict) -> list[list[tuple]]:
     return out
 
 
-def map_buckets(fn, grads: dict, state, cfg: dict, workload: dict):
+def map_buckets(fn, grads: dict, state, cfg: dict, workload: dict, arch):
     """``fn(flat, st) -> (flat, st)`` over every DDP bucket's flat gradient
     and its compressor state; returns the gradient rebuilt by path from
     the buckets ``fn`` returned, and the new states."""
     pieces: dict = {}
     new_state = []
-    for bkt, st in zip(buckets(cfg, workload), state):
+    for bkt, st in zip(buckets(cfg, workload, arch), state):
         flat = jnp.concatenate([
             (grads[p][l] if l is not None else grads[p]).reshape(-1)
             for p, l, _ in bkt])
@@ -204,7 +139,7 @@ def map_buckets(fn, grads: dict, state, cfg: dict, workload: dict):
             off += size
     rebuilt = {}
     for p, g in grads.items():
-        if p.startswith("blocks/"):
+        if stacked(p, arch.STACKS):
             rebuilt[p] = jnp.stack([pieces[(p, l)].reshape(g.shape[1:])
                                     for l in range(g.shape[0])])
         else:
@@ -213,11 +148,12 @@ def map_buckets(fn, grads: dict, state, cfg: dict, workload: dict):
 
 
 # --------------------------------------------------------------- readers
-def leaf_norms(tree: dict) -> dict:
-    """{label: norm} per leaf, per layer for stacked leaves."""
+def leaf_norms(tree: dict, stacks) -> dict:
+    """{label: norm} per leaf, per layer (``path#layer``) for the leaves of
+    ``stacks``."""
     out = {}
     for p, x in tree.items():
-        if p.startswith("blocks/"):
+        if stacked(p, stacks):
             n = jnp.sqrt(jnp.sum(x * x, axis=tuple(range(1, x.ndim))))
             for l in range(x.shape[0]):
                 out[f"{p}#{l}"] = n[l]
@@ -229,9 +165,10 @@ def leaf_norms(tree: dict) -> dict:
 # ------------------------------------------------------------------ step
 class Reference:
     """Three (or more) reference steps from the seed, on every device,
-    with ``compressor`` the cell's module from ``bench/compressors``."""
+    with ``arch`` the configuration's module from ``bench/models`` and
+    ``compressor`` the cell's module from ``bench/compressors``."""
 
-    def __init__(self, cfg: dict, workload: dict, compressor,
+    def __init__(self, cfg: dict, workload: dict, arch, compressor,
                  precision: str = "highest"):
         self.cfg, self.workload, self.precision = cfg, workload, precision
         devs = np.array(jax.devices())
@@ -247,28 +184,31 @@ class Reference:
                                      P(*([None] * (len(shape) - 1)), "b"))
             return rep
 
-        shapes = weights.shapes(cfg)
+        shapes = arch.shapes(cfg)
         self.p_sh = {p: rep for p in shapes}
         self.m_sh = {p: moment_sharding(s) for p, s in shapes.items()}
         self.b_sh = NamedSharding(self.mesh, P("b"))
 
         def init(key):
-            w = weights.make(key, cfg, w_dtype)
+            w = weights.make(key, cfg, w_dtype, arch)
             params = {p: v.astype(jnp.float32) for p, v in w.items()}
             zeros = {p: jnp.zeros(v.shape, jnp.float32)
                      for p, v in params.items()}
             return (params, {"m": zeros, "v": dict(zeros)},
-                    compressor.init(key, cfg, workload))
+                    compressor.init(key, cfg, workload, arch))
 
         self._init = jax.jit(init, out_shardings=(
             self.p_sh, {"m": self.m_sh, "v": self.m_sh}, rep))
 
+        einsum = _einsum_fn(precision)
+
         def step(params, moments, st, t, tokens, labels, lr):
             n_tok = jnp.maximum(jnp.sum(labels >= 0), 1).astype(jnp.float32)
             lsum, grads = jax.value_and_grad(
-                lambda p: loss_sum(p, tokens, labels, cfg, precision))(params)
+                lambda p: arch.loss_sum(p, tokens, labels, cfg, einsum)
+            )(params)
             grads = {p: g / n_tok for p, g in grads.items()}
-            grads, st = compressor.apply(grads, st, cfg, workload)
+            grads, st = compressor.apply(grads, st, cfg, workload, arch)
             gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in grads.values()))
             if opt["grad_clip"]:
                 scale = jnp.minimum(1.0, opt["grad_clip"]
@@ -287,7 +227,7 @@ class Reference:
                                         + opt["weight_decay"] * params[p])
                    for p in params}
             return new, {"m": m, "v": v}, st, lsum / n_tok, \
-                leaf_norms(grads), grads
+                leaf_norms(grads, arch.STACKS), grads
 
         self._step = jax.jit(
             step, donate_argnums=(0, 1, 2),
@@ -297,9 +237,9 @@ class Reference:
                            rep, rep, self.m_sh))
 
         def delta(params, key):
-            w = weights.make(key, cfg, w_dtype)
+            w = weights.make(key, cfg, w_dtype, arch)
             return leaf_norms({p: params[p] - w[p].astype(jnp.float32)
-                               for p in params})
+                               for p in params}, arch.STACKS)
 
         self._delta = jax.jit(delta)
 

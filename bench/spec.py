@@ -3,7 +3,12 @@
 ``BENCHMARK.json`` (at the checkout root) names the cells and metrics.
 Each configuration, cell and per-layer metric lives in a file of its own:
 
-- ``bench/configs/<config>.json``: the model's sizes, its source and cuts;
+- ``bench/configs/<config>.json``: the model's sizes, its source and cuts,
+  and ``arch``, the name of its architecture module;
+- ``bench/models/<arch>.py``: the architecture (``bench/models/llama.py``
+  says what such a module holds): its leaves, its plain reference
+  forward pass and loss, its FLOPs per token, and the program's
+  configuration for it;
 - ``bench/workloads/<cell>.json``: the job (batch, plan, optimizer, limits);
 - ``bench/metrics/<metric>.py``: a reader with ``LAYER``, ``UNIT``,
   ``MOVES`` and ``read(trace, record) -> float | None``;
@@ -12,8 +17,8 @@ Each configuration, cell and per-layer metric lives in a file of its own:
   holds);
 - ``bench/peaks.json``: the chip's peaks, keyed by ``device_kind``.
 
-A new cell, configuration or metric is new files plus new entries in
-``BENCHMARK.json``; nothing here changes.
+A new cell, configuration, architecture or metric is new files plus new
+entries in ``BENCHMARK.json``; nothing here changes.
 """
 from __future__ import annotations
 
@@ -89,6 +94,13 @@ class Spec:
     def reader(self, metric: str):
         """The module ``bench/metrics/<metric>.py``."""
         return self._module("metrics", metric)
+
+    def arch(self, cfg: dict):
+        """The module ``bench/models/<arch>.py`` of a configuration."""
+        if "arch" not in cfg:
+            raise SpecError(f"configuration {cfg.get('name')!r} names no "
+                            f"arch")
+        return self._module("models", cfg["arch"])
 
     def compressor(self, workload: dict):
         """The module ``bench/compressors/<compression>.py`` of a cell."""

@@ -25,7 +25,7 @@ def copy_bench(dst: str) -> str:
     """BENCHMARK.json and the files the benchmark finds by name (not the
     rest of its code) under ``dst``; returns ``dst``."""
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), dst)
-    for sub in ("configs", "workloads", "metrics", "compressors"):
+    for sub in ("configs", "models", "workloads", "metrics", "compressors"):
         shutil.copytree(os.path.join(BENCH, sub),
                         os.path.join(dst, "bench", sub))
     shutil.copy(os.path.join(BENCH, "peaks.json"),
@@ -49,9 +49,10 @@ def add_cell(root: str, name: str, workload: dict) -> None:
 
 @pytest.fixture(scope="module")
 def tiny_root(tmp_path_factory):
-    """A benchmark tree with two tiny cells on the CPU: ``tiny.powersgd``
-    (one device, PowerSGD as in the one-chip cell) and ``tiny.syncsgd4``
-    (four devices, syncSGD as in the four-chip cell)."""
+    """A benchmark tree with three tiny cells on the CPU: ``tiny.powersgd``
+    and ``tiny.syncsgd1`` (one device, PowerSGD and syncSGD as in the
+    one-chip cells) and ``tiny.syncsgd4`` (four devices, syncSGD as in the
+    four-chip cell)."""
     root = copy_bench(str(tmp_path_factory.mktemp("bench_root")))
     with open(os.path.join(BENCH, "configs", "smollm2-360m.json")) as f:
         cfg = dict(json.load(f), **TINY)
@@ -60,6 +61,7 @@ def tiny_root(tmp_path_factory):
         json.dump(cfg, f)
     for name, src, chips in (
             ("tiny.powersgd", "smollm2-360m.powersgd.1chip", 1),
+            ("tiny.syncsgd1", "smollm2-360m.syncsgd.1chip", 1),
             ("tiny.syncsgd4", "smollm2-1.7b.syncsgd.4chip", 4)):
         with open(os.path.join(BENCH, "workloads", src + ".json")) as f:
             w = json.load(f)

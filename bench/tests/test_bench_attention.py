@@ -36,3 +36,31 @@ def test_attention_kernel_ms(with_attention):
         assert got == pytest.approx(1e3 * 500e-6 / 2)
     else:
         assert "attention" not in summary["kernel_s"] and got is None
+
+
+def _powersgd_record(steps: int) -> dict:
+    sp = spec.Spec()
+    w = sp.workload("smollm2-360m.powersgd.1chip")
+    cfg = sp.config(w["config"])
+    return {"steps": steps, "workload": w, "cfg": cfg, "arch": sp.arch(cfg),
+            "peak": sp.peak("TPU v5 lite")}
+
+
+@pytest.mark.parametrize("metric", ["compress_kernel_ms",
+                                    "powersgd_roofline"])
+def test_compression_readers_read_only_grad_sync(metric):
+    """The PowerSGD readers take the kernels labelled ``grad_sync`` and
+    leave the ``attention`` kernels out; with none of their own they read
+    nothing."""
+    reader = spec.Spec().reader(metric)
+    record = _powersgd_record(2)
+    both = _trace([("grad_sync.7", 10, 40), ("attention.3", 100, 500)])
+    alone = _trace([("grad_sync.7", 10, 40)])
+    got = reader.read(both, record)
+    assert got is not None and got == reader.read(alone, record)
+    if metric == "compress_kernel_ms":
+        assert got == pytest.approx(1e3 * 40e-6 / 2)
+    else:
+        least, _ = reader.least_seconds(record)
+        assert got == pytest.approx(100.0 * least / (40e-6 / 2))
+    assert reader.read(_trace([("attention.3", 100, 500)]), record) is None

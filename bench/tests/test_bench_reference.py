@@ -19,20 +19,33 @@ def test_four_devices_sound_run_is_correct(tiny_root, tmp_path):
     assert res["device"]["count"] == 4
 
 
-def test_control_in_float8_is_not_correct(tiny_root):
+def _control_numbers(root, cell):
+    """The float8 control's numbers and the reference's own, for ``cell``
+    at a seed over 32 bits."""
     from bench.reference import Reference
-    sp = _spec(tiny_root)
-    w, cfg = sp.workload("tiny.powersgd"), sp.config("tiny")
-    comp = sp.compressor(w)
+    sp = _spec(root)
+    w, cfg = sp.workload(cell), sp.config("tiny")
+    arch, comp = sp.arch(cfg), sp.compressor(w)
     batches = data.batches(cfg, w, 2, 2**35 + 3, 3)
     key = weights.seed_key(2**35 + 3)
-    ref = Reference(cfg, w, comp).run(key, batches, keep_grad=True)
-    ctl = Reference(cfg, w, comp, precision="fp8").run(key, batches,
-                                                      keep_grad=True)
-    nums = compare.numbers(ctl, ref)
+    ref = Reference(cfg, w, arch, comp).run(key, batches, keep_grad=True)
+    ctl = Reference(cfg, w, arch, comp, precision="fp8").run(
+        key, batches, keep_grad=True)
+    return compare.numbers(ctl, ref), compare.numbers(ref, ref)
+
+
+def test_control_in_float8_is_not_correct(tiny_root):
+    nums, same = _control_numbers(tiny_root, "tiny.powersgd")
     assert not compare.verdict(nums, TINY_LIMITS), nums
     assert nums["grad_err"] > 2 * TINY_LIMITS["grad_err"], nums
-    same = compare.numbers(ref, ref)
+    assert compare.verdict(same, TINY_LIMITS), same
+
+
+def test_control_in_float8_fails_the_syncsgd_cell(tiny_root):
+    """The one-chip syncSGD cell's control: with no compressor the float8
+    gradient is caught by ``grad_err``."""
+    nums, same = _control_numbers(tiny_root, "tiny.syncsgd1")
+    assert nums["grad_err"] > 2 * TINY_LIMITS["grad_err"], nums
     assert compare.verdict(same, TINY_LIMITS), same
 
 
@@ -58,8 +71,9 @@ def test_buckets_match_the_program(tiny_root):
     from bench.reference import buckets
     sp = _spec(tiny_root)
     w, cfg = sp.workload("tiny.powersgd"), sp.config("tiny")
+    arch = sp.arch(cfg)
     program.add_src_path()
     from repro.train import overlap
-    prog = program.Program(cfg, w)
+    prog = program.Program(cfg, w, arch)
     assert overlap.build_layout(prog.setup).layout.sizes == tuple(
-        sum(leaf[2] for leaf in b) for b in buckets(cfg, w))
+        sum(leaf[2] for leaf in b) for b in buckets(cfg, w, arch))

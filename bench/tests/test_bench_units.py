@@ -17,23 +17,25 @@ def _cfg(name):
 
 def test_smollm2_360m_counts():
     cfg = _cfg("smollm2-360m")
+    arch = SP.arch(cfg)
     # per layer: q,o 960x960, k,v 960x320, gate/up/down 960x2560
     per_layer = 2 * 960 * 960 + 2 * 960 * 320 + 3 * 960 * 2560
     assert per_layer == 9_830_400
-    assert flops.matmul_params(cfg) == 32 * per_layer + 49152 * 960
-    assert flops.param_count(cfg) == 361_821_120          # 361.8M
+    assert arch.matmul_params(cfg) == 32 * per_layer + 49152 * 960
+    assert arch.param_count(cfg) == 361_821_120          # 361.8M
     attn = 6 * 32 * 2048 * 15 * 64
-    assert flops.train_flops_per_token(cfg, 2048) == \
+    assert arch.train_flops_per_token(cfg, 2048) == \
         6 * 361_758_720 + attn == 2_548_039_680           # 2.55 GFLOP
 
 
 def test_smollm2_1p7b_cut_counts():
     cfg = _cfg("smollm2-1.7b")
+    arch = SP.arch(cfg)
     assert cfg["n_layers"] == 10 and cfg["reduced"] == {"n_layers": 24}
     per_layer = 4 * 2048 * 2048 + 3 * 2048 * 8192
-    assert flops.param_count(cfg) == \
+    assert arch.param_count(cfg) == \
         10 * (per_layer + 2 * 2048) + 49152 * 2048 + 2048 == 771_794_944
-    assert flops.train_flops_per_token(cfg, 2048) == \
+    assert arch.train_flops_per_token(cfg, 2048) == \
         6 * (10 * per_layer + 49152 * 2048) + 6 * 10 * 2048 * 32 * 64 \
         == 4_882_169_856                                  # 4.88 GFLOP
 
@@ -70,6 +72,7 @@ def test_benchmark_entries_have_their_files():
         cfg = json.load(open(os.path.join(ROOT, c["file"])))
         assert cfg["name"] == c["name"] and cfg["source"] == c["source"]
         assert sorted(cfg["reduced"]) == sorted(c["reduced"])
+        assert callable(SP.arch(cfg).loss_sum), c["name"]
     for w in b["workloads"]:
         wl = SP.workload(w["name"])
         assert wl["limits"] and set(wl["limits"]) <= set(
@@ -108,12 +111,12 @@ def test_new_files_are_found_by_name(tmp_path):
         json.dump(b, f)
     with open(os.path.join(root, "bench", "compressors", "qsgd.py"),
               "w") as f:
-        f.write("def init(key, cfg, workload):\n    return ()\n")
+        f.write("def init(key, cfg, workload, arch):\n    return ()\n")
     sp = spec.Spec(os.path.join(root, "bench"))
     assert sp.config(sp.workload("other-model.cell")["config"])[
         "n_layers"] == 4
     assert sp.compressor({"plan": {"compression": "qsgd"}}).init(
-        None, {}, {}) == ()
+        None, {}, {}, None) == ()
     names = [m["name"] for m in sp.per_layer("other-model.cell")]
     assert "steps_seen" in names and "compress_kernel_ms" not in names
     assert sp.reader("steps_seen").read({}, {"steps": 5}) == 5

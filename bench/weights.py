@@ -2,13 +2,8 @@
 starting factors.  Both the run and the reference make them here, so the
 reference takes nothing that the program made.
 
-Weights are named by path in the layout of a Llama-style decoder with its
-layers stacked on a leading axis and matrices stored (in, out):
-
-    embed/table (V, d)            final_norm/scale (d,)
-    blocks/attn/{wq,wk,wv,wo}/w   blocks/{ln1,ln2}/scale (L, d)
-    blocks/mlp/{gate,up,down}/w
-
+Weights are named by path in the layout that the configuration's
+architecture module gives (``shapes`` in ``bench/models/<arch>.py``).
 Each leaf is drawn from the seed's key folded with a hash of its path, so a
 leaf's values do not depend on which other leaves exist.
 """
@@ -38,29 +33,6 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def shapes(cfg: dict) -> dict[str, tuple[int, ...]]:
-    """Path -> shape of every trained leaf."""
-    L, d, ff, V = cfg["n_layers"], cfg["d_model"], cfg["d_ff"], cfg["vocab"]
-    q = cfg["n_heads"] * cfg["head_dim"]
-    kv = cfg["n_kv_heads"] * cfg["head_dim"]
-    out = {
-        "embed/table": (V, d),
-        "final_norm/scale": (d,),
-        "blocks/attn/wq/w": (L, d, q),
-        "blocks/attn/wk/w": (L, d, kv),
-        "blocks/attn/wv/w": (L, d, kv),
-        "blocks/attn/wo/w": (L, q, d),
-        "blocks/ln1/scale": (L, d),
-        "blocks/ln2/scale": (L, d),
-        "blocks/mlp/gate/w": (L, d, ff),
-        "blocks/mlp/up/w": (L, d, ff),
-        "blocks/mlp/down/w": (L, ff, d),
-    }
-    if not cfg["tie_embeddings"]:
-        out["unembed/table"] = (V, d)
-    return out
-
-
 def leaf(key: jax.Array, path: str, shape, std: float, dtype) -> jax.Array:
     """One leaf: RMSNorm scales are ones, matrices N(0, std^2)."""
     if path.endswith("/scale"):
@@ -69,10 +41,11 @@ def leaf(key: jax.Array, path: str, shape, std: float, dtype) -> jax.Array:
     return (std * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
 
 
-def make(key: jax.Array, cfg: dict, dtype) -> dict[str, jax.Array]:
-    """Every leaf, by path (call under ``jax.jit``)."""
+def make(key: jax.Array, cfg: dict, dtype, arch) -> dict[str, jax.Array]:
+    """Every leaf of architecture module ``arch``, by path (call under
+    ``jax.jit``)."""
     return {p: leaf(key, p, s, cfg["init_std"], dtype)
-            for p, s in shapes(cfg).items()}
+            for p, s in arch.shapes(cfg).items()}
 
 
 def powersgd_q(key: jax.Array, bucket: int, cols: int, rank: int
