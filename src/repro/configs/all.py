@@ -9,3 +9,4 @@ import repro.configs.zamba2_2_7b       # noqa: F401
 import repro.configs.qwen2_vl_7b       # noqa: F401
 import repro.configs.xlstm_350m        # noqa: F401
 import repro.configs.seamless_m4t_medium  # noqa: F401
+import repro.configs.moonlight_16b_a3b  # noqa: F401

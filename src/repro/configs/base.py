@@ -20,6 +20,38 @@ class MoEConfig:
     dense_residual: bool = False  # arctic: dense FFN residual in parallel w/ MoE
     capacity_factor: float = 1.25
     router_dtype: str = "float32"
+    d_expert: int = 0             # the drop-free layer's expert width
+                                  # (the capacity layer's is d_ff)
+    routed_scale: float = 1.0     # the drop-free layer's routed output factor
+    shared_gate: bool = True      # qwen2-moe's sigmoid gate on shared experts
+    # the drop-free layer (models/moe.dropfree_apply; DeepSeek-V3 routing:
+    # sigmoid scores, renormalised top-k): no capacity, every routed
+    # token-slot computed; it holds experts [first_held, first_held +
+    # n_held) of the n_experts it routes over (0 => all)
+    drop_free: bool = False
+    n_held: int = 0
+    first_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): q and k heads of
+    ``qk_nope_head_dim + qk_rope_head_dim``, keys and values expanded per
+    head from a ``kv_lora_rank`` latent, v heads of ``v_head_dim``."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    q_lora_rank: Optional[int] = None
+    kv_norm_eps: float = 1e-6
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -128,10 +160,19 @@ class ArchConfig:
     block_pattern: str = "all_attn"
     sub_quadratic: bool = False           # True => long_500k shape is runnable
     max_seq: int = 131072
+    mla: Optional[MLAConfig] = None       # latent attention in every layer
+    # moe: the first ``first_k_dense`` layers are dense (d_ff), the rest
+    # MoE; with it the model has two stacks, ``dense_blocks`` and
+    # ``moe_blocks`` (models/model.stack_keys)
+    first_k_dense: int = 0
 
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        # MoE and MLA may come as plain dicts (bench/models/*.py)
+        for name, cls in (("moe", MoEConfig), ("mla", MLAConfig)):
+            if isinstance(getattr(self, name), dict):
+                object.__setattr__(self, name, cls(**getattr(self, name)))
 
     # ---- derived quantities used by the perf model / roofline ----
     def param_count(self) -> int:
@@ -179,9 +220,14 @@ def reduced(cfg: ArchConfig, **overrides) -> ArchConfig:
         max_seq=512,
     )
     if cfg.moe.n_experts:
-        kw["moe"] = dataclasses.replace(cfg.moe, n_experts=4,
-                                        top_k=min(cfg.moe.top_k, 2),
-                                        n_shared=min(cfg.moe.n_shared, 1))
+        kw["moe"] = dataclasses.replace(
+            cfg.moe, n_experts=4, top_k=min(cfg.moe.top_k, 2),
+            n_shared=min(cfg.moe.n_shared, 1), d_expert=kw["d_ff"],
+            n_held=min(cfg.moe.n_held, 2), first_held=0)
+    if cfg.mla is not None:
+        kw["mla"] = dataclasses.replace(cfg.mla, kv_lora_rank=64,
+                                        qk_nope_head_dim=32,
+                                        qk_rope_head_dim=16, v_head_dim=32)
     if cfg.family in ("hybrid", "ssm"):
         kw["ssm"] = dataclasses.replace(
             cfg.ssm, state_dim=16, head_dim=16, chunk=32,

@@ -1,9 +1,10 @@
 """Pallas TPU flash attention for training and prefill: forward, dK/dV and
 dQ kernels behind one ``jax.custom_vjp``.
 
-Layout: head-major ``q (B, H, S, hd)``, ``k, v (B, KVh, S, hd)`` with
-``KVh | H``; the caller (``kernels/ops.py``) transposes from the model's
-``(B, S, heads, hd)``.  Grouped-query attention maps q head ``h`` to kv
+Layout: head-major ``q, k (B, H|KVh, S, hd)`` and ``v (B, KVh, S, dv)``
+with ``KVh | H`` and ``dv <= hd`` (latent attention has q/k heads of 192
+and v heads of 128); the caller (``kernels/ops.py``) transposes from the
+model's ``(B, S, heads, hd)``.  Grouped-query attention maps q head ``h`` to kv
 head ``h // (H // KVh)`` in the k/v index maps, so k and v are never
 repeated.
 
@@ -46,10 +47,12 @@ _F32 = jnp.float32
 _NAME = "attention"
 
 
-def fits(sq: int, sk: int, hd: int) -> bool:
+def fits(sq: int, sk: int, hd: int, dv: int) -> bool:
     """Whether self-attention of these shapes takes the kernel: equal q and
-    k lengths in whole 128-row blocks, and a head size the MXU tiles."""
-    return sq == sk and sq % LANES == 0 and hd % 64 == 0 and hd <= 256
+    k lengths in whole 128-row blocks, and q/k and v head sizes the MXU
+    tiles (multiples of 64 up to 256, v's no larger than q's)."""
+    return (sq == sk and sq % LANES == 0 and hd % 64 == 0 and hd <= 256
+            and dv % 64 == 0 and dv <= hd)
 
 
 def _divisor(s: int, cap: int) -> int:
@@ -75,6 +78,8 @@ def _lanes(x, n: int):
     """(r, 128) with equal lanes -> (r, n)."""
     if n <= LANES:
         return x[:, :n]
+    if n % LANES:
+        return jnp.tile(x, (1, -(-n // LANES)))[:, :n]
     return jnp.tile(x, (1, n // LANES))
 
 
@@ -171,6 +176,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
 def _forward(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
              interpret: bool):
     b, h, s, hd = q.shape
+    dv = v.shape[-1]
     group = h // k.shape[1]
     nq, nk = s // bq, s // bk
 
@@ -179,7 +185,8 @@ def _forward(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                bq=bq, bk=bk, nk=nk)
-    flops = 4 * b * h * s * s * hd // (2 if causal else 1)
+    causal_div = 2 if causal else 1
+    flops = 2 * b * h * s * s * (hd + dv) // causal_div
     return pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
@@ -189,26 +196,27 @@ def _forward(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
             pl.BlockSpec((None, None, bk, hd),
                          lambda b_, h_, i, j: (b_, h_ // group,
                                                kv_block(i, j), 0)),
-            pl.BlockSpec((None, None, bk, hd),
+            pl.BlockSpec((None, None, bk, dv),
                          lambda b_, h_, i, j: (b_, h_ // group,
                                                kv_block(i, j), 0)),
         ],
         out_specs=[
-            pl.BlockSpec((None, None, bq, hd),
+            pl.BlockSpec((None, None, bq, dv),
                          lambda b_, h_, i, j: (b_, h_, i, 0)),
             pl.BlockSpec((None, None, SUBLANES, bq),
                          lambda b_, h_, i, j: (b_, h_, 0, i)),
         ],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((b, h, s, dv), q.dtype),
                    jax.ShapeDtypeStruct((b, h, SUBLANES, s), _F32)],
         scratch_shapes=[pltpu.VMEM((bq, LANES), _F32),
                         pltpu.VMEM((bq, LANES), _F32),
-                        pltpu.VMEM((bq, hd), _F32)],
+                        pltpu.VMEM((bq, dv), _F32)],
         compiler_params=_params("parallel", "parallel", "parallel",
                                 "arbitrary", vmem=_vmem_bytes(bq, bk, hd)),
         cost_estimate=pl.CostEstimate(
-            flops=flops, transcendentals=flops // (4 * hd),
-            bytes_accessed=(2 * q.size + 2 * k.size * nq) * q.dtype.itemsize),
+            flops=flops, transcendentals=b * h * s * s // causal_div,
+            bytes_accessed=(2 * q.size * dv // hd + (k.size + v.size) * nq)
+            * q.dtype.itemsize),
         interpret=interpret,
         name=_NAME,
     )(q, k, v)
@@ -254,7 +262,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
 def _backward_dkv(q, k, v, do, lse, di, *, causal: bool, scale: float,
                   bq: int, bk: int, interpret: bool):
     b, h, s, hd = q.shape
-    kvh = k.shape[1]
+    kvh, dv = k.shape[1], v.shape[-1]
     group = h // kvh
     nq, nk = s // bq, s // bk
 
@@ -272,30 +280,32 @@ def _backward_dkv(q, k, v, do, lse, di, *, causal: bool, scale: float,
 
     kernel = functools.partial(_dkv_kernel, scale=scale, causal=causal,
                                bq=bq, bk=bk, nq=nq, group=group)
-    flops = 8 * b * h * s * s * hd // (2 if causal else 1)
+    causal_div = 2 if causal else 1
+    flops = 4 * b * h * s * s * (hd + dv) // causal_div
     return pl.pallas_call(
         kernel,
         grid=(b, kvh, nk, group, nq),
         in_specs=[
             pl.BlockSpec((None, None, bq, hd), q_map),
             pl.BlockSpec((None, None, bk, hd), kv_map),
-            pl.BlockSpec((None, None, bk, hd), kv_map),
-            pl.BlockSpec((None, None, bq, hd), q_map),
+            pl.BlockSpec((None, None, bk, dv), kv_map),
+            pl.BlockSpec((None, None, bq, dv), q_map),
             pl.BlockSpec((None, None, SUBLANES, bq), row_map),
             pl.BlockSpec((None, None, SUBLANES, bq), row_map),
         ],
         out_specs=[pl.BlockSpec((None, None, bk, hd), kv_map),
-                   pl.BlockSpec((None, None, bk, hd), kv_map)],
+                   pl.BlockSpec((None, None, bk, dv), kv_map)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, hd), _F32),
-                        pltpu.VMEM((bk, hd), _F32)],
+                        pltpu.VMEM((bk, dv), _F32)],
         compiler_params=_params("parallel", "parallel", "parallel",
                                 "arbitrary", "arbitrary",
                                 vmem=_vmem_bytes(bq, bk, hd)),
         cost_estimate=pl.CostEstimate(
-            flops=flops, transcendentals=flops // (8 * hd),
-            bytes_accessed=(2 * q.size * nk + 4 * k.size) * q.dtype.itemsize),
+            flops=flops, transcendentals=b * h * s * s // causal_div,
+            bytes_accessed=(q.size * (hd + dv) // hd * nk
+                            + 2 * (k.size + v.size)) * q.dtype.itemsize),
         interpret=interpret,
         name=_NAME,
     )(q, k, v, do, lse, di)
@@ -336,6 +346,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_sc,
 def _backward_dq(q, k, v, do, lse, di, *, causal: bool, scale: float,
                  bq: int, bk: int, interpret: bool):
     b, h, s, hd = q.shape
+    dv = v.shape[-1]
     group = h // k.shape[1]
     nq, nk = s // bq, s // bk
 
@@ -353,15 +364,16 @@ def _backward_dq(q, k, v, do, lse, di, *, causal: bool, scale: float,
 
     kernel = functools.partial(_dq_kernel, scale=scale, causal=causal,
                                bq=bq, bk=bk, nk=nk)
-    flops = 6 * b * h * s * s * hd // (2 if causal else 1)
+    causal_div = 2 if causal else 1
+    flops = 2 * b * h * s * s * (2 * hd + dv) // causal_div
     return pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
         in_specs=[
             pl.BlockSpec((None, None, bq, hd), q_map),
             pl.BlockSpec((None, None, bk, hd), kv_map),
-            pl.BlockSpec((None, None, bk, hd), kv_map),
-            pl.BlockSpec((None, None, bq, hd), q_map),
+            pl.BlockSpec((None, None, bk, dv), kv_map),
+            pl.BlockSpec((None, None, bq, dv), q_map),
             pl.BlockSpec((None, None, SUBLANES, bq), row_map),
             pl.BlockSpec((None, None, SUBLANES, bq), row_map),
         ],
@@ -371,8 +383,9 @@ def _backward_dq(q, k, v, do, lse, di, *, causal: bool, scale: float,
         compiler_params=_params("parallel", "parallel", "parallel",
                                 "arbitrary", vmem=_vmem_bytes(bq, bk, hd)),
         cost_estimate=pl.CostEstimate(
-            flops=flops, transcendentals=flops // (6 * hd),
-            bytes_accessed=(3 * q.size + 2 * k.size * nq) * q.dtype.itemsize),
+            flops=flops, transcendentals=b * h * s * s // causal_div,
+            bytes_accessed=(q.size * (2 * hd + dv) // hd
+                            + (k.size + v.size) * nq) * q.dtype.itemsize),
         interpret=interpret,
         name=_NAME,
     )(q, k, v, do, lse, di)
@@ -414,8 +427,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool, softmax_scale: float | None = None,
                     blocks: tuple[int, int, int, int] | None = None,
                     interpret: bool = False) -> jax.Array:
-    """Self-attention over positions ``0..S-1``.  q: (B, H, S, hd); k, v:
-    (B, KVh, S, hd) with ``KVh | H``; returns (B, H, S, hd) in q's dtype.
+    """Self-attention over positions ``0..S-1``.  q: (B, H, S, hd); k:
+    (B, KVh, S, hd), v: (B, KVh, S, dv) with ``KVh | H`` and ``dv <= hd``;
+    returns (B, H, S, dv) in q's dtype; the softmax scale defaults to
+    ``hd ** -0.5``.
 
     ``blocks`` (forward q, k rows; backward q, k rows) defaults to
     :func:`block_sizes` of the shapes."""

@@ -15,6 +15,7 @@ from __future__ import annotations
 import collections
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import ref
 
@@ -89,17 +90,18 @@ def qsgd_quantize(g, norm, levels, key, *, force=None):
     return ref.qsgd_quantize(g, norm, levels, key)
 
 
-def flash_attention_fits(q_shape, k_shape, *, force=None) -> bool:
+def flash_attention_fits(q_shape, k_shape, v_shape, *, force=None) -> bool:
     """Whether self-attention of these (B, S, heads, hd) shapes takes the
     Pallas flash kernel: on TPU (or ``force='pallas'``), and where the
-    kernel's tiling takes the sequence length and head size."""
+    kernel's tiling takes the sequence length and head sizes."""
     from repro.kernels import flash_attention as k
-    return _use_pallas(force) and k.fits(q_shape[1], k_shape[1], q_shape[3])
+    return _use_pallas(force) and k.fits(q_shape[1], k_shape[1],
+                                         q_shape[3], v_shape[3])
 
 
 def flash_attention(q, k, v, *, causal, softmax_scale=None):
     """Causal or full self-attention over positions 0..S-1 on the Pallas
-    kernel.  q: (B, S, L, hd); k, v: (B, S, KVh, hd) -> (B, S, L, hd)."""
+    kernel.  q, k: (B, S, L|KVh, hd); v: (B, S, KVh, dv) -> (B, S, L, dv)."""
     from repro.kernels import flash_attention as fa
     heads_major = (0, 2, 1, 3)
     out = fa.flash_attention(
@@ -108,3 +110,16 @@ def flash_attention(q, k, v, *, causal, softmax_scale=None):
         softmax_scale=softmax_scale,
         interpret=jax.default_backend() != "tpu")
     return out.transpose(heads_major)
+
+
+def grouped_matmul(x, w, group_tiles, *, tm: int, force=None):
+    """``x (M, K)``, rows sorted by group in whole ``tm``-row tiles
+    (``group_tiles`` per group), times ``w (G, K, N)`` -> (M, N): the
+    Pallas kernel ``kernels/gmm.py`` (named ``moe_gmm``) on TPU,
+    ``jax.lax.ragged_dot`` elsewhere (rows past the groups read zero
+    there and are left unwritten by the kernel)."""
+    if _use_pallas(force):
+        from repro.kernels import gmm
+        return gmm.grouped_matmul(x, w, group_tiles, tm,
+                                  jax.default_backend() != "tpu")
+    return jax.lax.ragged_dot(x, w, (group_tiles * tm).astype(jnp.int32))

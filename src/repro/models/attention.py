@@ -51,7 +51,8 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       q_chunk: int = 2048,
                       softmax_scale: Optional[float] = None,
                       force: Optional[str] = None) -> jax.Array:
-    """q: (B, Sq, L, hd); k, v: (B, Sk, KVh, hd) with KVh | L.
+    """q, k: (B, Sq|Sk, L|KVh, hd); v: (B, Sk, KVh, dv) with KVh | L; the
+    result is (B, Sq, L, dv).
 
     Positions ``None`` mean ``0..S-1``.  Self-attention over those takes
     the Pallas flash kernel where ``ops.flash_attention_fits`` (``force``
@@ -61,7 +62,8 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     materializes (DESIGN.md §4).
     """
     if q_positions is None and k_positions is None and \
-            ops.flash_attention_fits(q.shape, k.shape, force=force):
+            ops.flash_attention_fits(q.shape, k.shape, v.shape,
+                                     force=force):
         ops.ATTENTION_PATHS["pallas"] += 1
         return ops.flash_attention(q, k, v, causal=causal,
                                    softmax_scale=softmax_scale)
@@ -91,7 +93,8 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             return (), out
 
         _, outs = jax.lax.scan(qstep, (), (qs, qp))
-        out = jnp.moveaxis(outs, 0, 1).reshape(b, nq * q_chunk, l, hd)
+        out = jnp.moveaxis(outs, 0, 1).reshape(b, nq * q_chunk, l,
+                                               v.shape[-1])
         return out[:, :sq]
     return _attention_qblock(q, k, v, causal=causal,
                              q_positions=q_positions,
@@ -105,7 +108,7 @@ def _attention_qblock(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       softmax_scale: Optional[float]) -> jax.Array:
     """Online-softmax over k/v chunks for ONE q block."""
     b, sq, l, hd = q.shape
-    sk, kvh = k.shape[1], k.shape[2]
+    sk, kvh, dv = k.shape[1], k.shape[2], v.shape[-1]
     scale = softmax_scale if softmax_scale is not None else hd ** -0.5
     chunk = min(chunk, sk)
     pad = (-sk) % chunk
@@ -118,7 +121,7 @@ def _attention_qblock(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     qg = _group(q, kvh).astype(jnp.float32) * scale   # (B,Sq,KVh,G,hd)
     kc = k.reshape(b, nc, chunk, kvh, hd)
-    vc = v.reshape(b, nc, chunk, kvh, hd)
+    vc = v.reshape(b, nc, chunk, kvh, dv)
     pc = k_positions.reshape(b, nc, chunk)
 
     # flash-attention structure: the per-chunk scores/probabilities are
@@ -145,13 +148,13 @@ def _attention_qblock(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     m0 = jnp.full((b, sq, kvh, l // kvh), NEG_INF, jnp.float32)
     d0 = jnp.zeros((b, sq, kvh, l // kvh), jnp.float32)
-    a0 = jnp.zeros((b, sq, kvh, l // kvh, hd), jnp.float32)
+    a0 = jnp.zeros((b, sq, kvh, l // kvh, dv), jnp.float32)
     (m, den, acc), _ = jax.lax.scan(
         step, (m0, d0, a0),
         (jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0),
          jnp.moveaxis(pc, 1, 0)))
     out = acc / jnp.maximum(den, 1e-30)[..., None]
-    return out.reshape(b, sq, l, hd).astype(q.dtype)
+    return out.reshape(b, sq, l, dv).astype(q.dtype)
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
@@ -190,4 +193,4 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         den = jax.lax.psum(den, seq_shard_axes)
         pv = jax.lax.psum(pv, seq_shard_axes)
     out = pv / jnp.maximum(den, 1e-30)[..., None]
-    return out.reshape(b, 1, l, hd).astype(q.dtype)
+    return out.reshape(b, 1, l, v_cache.shape[-1]).astype(q.dtype)

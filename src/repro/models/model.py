@@ -4,7 +4,9 @@ One class serves all 10 assigned architectures (DESIGN.md §5).  Families
 differ only in their *stack*:
 
   dense / vlm   scan over L × (attn + SwiGLU)          [vlm: M-RoPE, embeds-in]
-  moe           scan over L × (attn + MoE FFN)
+  moe           scan over L × (attn + MoE FFN); with ``first_k_dense``
+                two stacks: ``dense_blocks`` (attn + SwiGLU) then
+                ``moe_blocks`` (attn + drop-free MoE FFN)  [deepseek-v3]
   hybrid        scan over G groups × (shared attn block w/ per-group LoRA
                 + inner scan over mamba layers)        [zamba2]
   ssm           scan over G groups × (7 mLSTM + 1 sLSTM)  [xlstm]
@@ -131,6 +133,17 @@ def _lora_patch(w_params, lora, ctx: ShardCtx):
     return {**w_params, "w": w_params["w"] + delta}
 
 
+def stack_keys(cfg: ArchConfig) -> tuple[str, ...]:
+    """The scanned param collections of a model, in FORWARD order."""
+    if cfg.family == "moe" and cfg.first_k_dense:
+        return ("dense_blocks", "moe_blocks")
+    if cfg.family in ("hybrid", "ssm"):
+        return ("groups",)
+    if cfg.family == "audio":
+        return ("enc_blocks", "dec_blocks")
+    return ("blocks",)
+
+
 # --------------------------------------------------------------------------
 # Model
 # --------------------------------------------------------------------------
@@ -152,6 +165,13 @@ class Model:
             p, s = _stack(lambda k: tf.dense_block_init(k, cfg, ctx),
                           k_stack, cfg.n_layers)
             params["blocks"], specs["blocks"] = p, s
+        elif self.family == "moe" and cfg.first_k_dense:
+            p, s = _stack(lambda k: tf.dense_block_init(k, cfg, ctx),
+                          k_extra, cfg.first_k_dense)
+            params["dense_blocks"], specs["dense_blocks"] = p, s
+            p, s = _stack(lambda k: moe_mod.moe_block_init(k, cfg, ctx),
+                          k_stack, cfg.n_layers - cfg.first_k_dense)
+            params["moe_blocks"], specs["moe_blocks"] = p, s
         elif self.family == "moe":
             p, s = _stack(lambda k: moe_mod.moe_block_init(k, cfg, ctx),
                           k_stack, cfg.n_layers)
@@ -245,7 +265,8 @@ class Model:
         return x, aux
 
     def _run_blocks(self, params, x, aux, ctx, st: StepState, caches):
-        """Dispatch to the family stack.  Returns (x, new_caches, moe_aux).
+        """Dispatch to the family stack.  Returns (x, new_caches, MoE aux
+        loss, MoE counters: empty but for the drop-free layer).
 
         Train mode scans blocks with remat; prefill/decode carry the FULL
         stacked cache through the scan and update layer l in place
@@ -263,29 +284,11 @@ class Model:
                     y, _ = _remat(fn, remat)(p_l, carry, cache=None)
                     return y, None
                 x, _ = jax.lax.scan(body, x, params["blocks"])
-                return x, None, 0.0
+                return x, None, 0.0, {}
             x, caches = _scan_with_cache(fn, params["blocks"], x, caches)
-            return x, caches, 0.0
+            return x, caches, 0.0, {}
         if fam == "moe":
-            if st.training:
-                def body(carry, p_l):
-                    y, acc = carry
-                    fn = _remat(partial(moe_mod.moe_block_apply, aux=aux,
-                                        ctx=ctx, cfg=cfg, st=st), remat)
-                    y, _, al = fn(p_l, y, cache=None)
-                    return (y, acc + al), None
-                (x, aux_loss), _ = jax.lax.scan(
-                    body, (x, jnp.float32(0.0)), params["blocks"])
-                return x, None, aux_loss / cfg.n_layers
-
-            def moe_fn(p_l, y, cache):
-                y, nc, _ = moe_mod.moe_block_apply(p_l, y, aux=aux, ctx=ctx,
-                                                   cfg=cfg, st=st,
-                                                   cache=cache)
-                return y, nc
-            x, caches = _scan_with_cache(moe_fn, params["blocks"], x,
-                                         caches)
-            return x, caches, 0.0
+            return self._run_moe(params, x, aux, ctx, st, caches, remat)
         if fam == "hybrid":
             shared = params["shared"]
             fn = partial(self._zamba_group_apply, shared=shared, aux=aux,
@@ -295,9 +298,9 @@ class Model:
                     y, _ = _remat(fn, remat)(p_g, carry, cache=None)
                     return y, None
                 x, _ = jax.lax.scan(body, x, params["groups"])
-                return x, None, 0.0
+                return x, None, 0.0, {}
             x, caches = _scan_with_cache(fn, params["groups"], x, caches)
-            return x, caches, 0.0
+            return x, caches, 0.0, {}
         if fam == "ssm":
             fn = partial(self._xlstm_group_apply, ctx=ctx, st=st,
                          remat=remat)
@@ -306,10 +309,50 @@ class Model:
                     y, _ = _remat(fn, remat)(p_g, carry, cache=None)
                     return y, None
                 x, _ = jax.lax.scan(body, x, params["groups"])
-                return x, None, 0.0
+                return x, None, 0.0, {}
             x, caches = _scan_with_cache(fn, params["groups"], x, caches)
-            return x, caches, 0.0
+            return x, caches, 0.0, {}
         raise ValueError(fam)
+
+    def _run_moe(self, params, x, aux, ctx, st: StepState, caches,
+                 remat: str):
+        """The MoE stack, ``blocks``; or with ``first_k_dense``,
+        ``dense_blocks`` then ``moe_blocks``.  Returns (x, caches, the MoE
+        layers' mean aux loss, their merged counters)."""
+        cfg = self.cfg
+        dense_fn = partial(tf.dense_block_apply, aux=aux, ctx=ctx, cfg=cfg,
+                           st=st)
+        moe_fn = partial(moe_mod.moe_block_apply, aux=aux, ctx=ctx,
+                         cfg=cfg, st=st)
+        moe_key = stack_keys(cfg)[-1]
+        if st.training:
+            def dense_body(carry, p_l):
+                y, _ = _remat(dense_fn, remat)(p_l, carry, cache=None)
+                return y, None
+
+            def moe_body(carry, p_l):
+                y, acc = carry
+                y, _, al, stats = _remat(moe_fn, remat)(p_l, y, cache=None)
+                return (y, acc + al), stats
+            if cfg.first_k_dense:
+                x, _ = jax.lax.scan(dense_body, x, params["dense_blocks"])
+            (x, aux_loss), stats = jax.lax.scan(
+                moe_body, (x, jnp.float32(0.0)), params[moe_key])
+            return (x, None, aux_loss / (cfg.n_layers - cfg.first_k_dense),
+                    moe_mod.total_stats(stats))
+
+        def moe_cached(p_l, y, cache):
+            y, nc, _, _ = moe_fn(p_l, y, cache=cache)
+            return y, nc
+        if not cfg.first_k_dense:
+            x, caches = _scan_with_cache(moe_cached, params[moe_key], x,
+                                         caches)
+            return x, caches, 0.0, {}
+        x, c_dense = _scan_with_cache(dense_fn, params["dense_blocks"], x,
+                                      caches["dense_blocks"])
+        x, c_moe = _scan_with_cache(moe_cached, params[moe_key], x,
+                                    caches[moe_key])
+        return x, {"dense_blocks": c_dense, moe_key: c_moe}, 0.0, {}
 
     def _zamba_group_apply(self, p_g, x, shared, aux, ctx, st, cache=None,
                            remat="none"):
@@ -396,19 +439,22 @@ class Model:
 
     # ---------------- public entry points ----------------
     def loss(self, params, batch, ctx: ShardCtx):
-        """Returns (loss_sum_local, n_tokens_local, moe_aux_loss)."""
+        """Returns (loss_sum_local, n_tokens_local, moe_aux_loss,
+        moe_stats): the capacity layers' aux loss (0 elsewhere) and the
+        drop-free layers' counters (empty elsewhere; ``moe.merge_stats``)."""
         cfg = self.cfg
         st = StepState(mode="train")
         if self.family == "audio":
             memory = self._encode(params, batch["enc_embeds"], ctx)
             x, aux = self._embed_in(params, batch, ctx)
-            x, _, moe_aux = (* self._run_decoder(params, x, aux, ctx, st,
-                                                 None, memory), 0.0)
+            x, _ = self._run_decoder(params, x, aux, ctx, st, None, memory)
+            moe_aux, stats = 0.0, {}
         else:
             x, aux = self._embed_in(params, batch, ctx)
-            x, _, moe_aux = self._run_blocks(params, x, aux, ctx, st, None)
+            x, _, moe_aux, stats = self._run_blocks(params, x, aux, ctx, st,
+                                                    None)
         loss_sum, n_tok = tf.lm_loss(params, x, batch["labels"], ctx, cfg)
-        return loss_sum, n_tok, moe_aux
+        return loss_sum, n_tok, moe_aux, stats
 
     def prefill(self, params, batch, ctx: ShardCtx, caches):
         """Returns (last-position vocab-parallel logits, filled caches)."""
@@ -420,7 +466,8 @@ class Model:
                                           memory)
         else:
             x, aux = self._embed_in(params, batch, ctx)
-            x, caches, _ = self._run_blocks(params, x, aux, ctx, st, caches)
+            x, caches, _, _ = self._run_blocks(params, x, aux, ctx, st,
+                                                  caches)
         logits = tf.lm_logits(params, x[:, -1:], ctx, self.cfg)
         return logits[:, 0], caches
 
@@ -439,7 +486,8 @@ class Model:
             x, caches = self._run_decoder(params, x, aux, ctx, st, caches,
                                           None)
         else:
-            x, caches, _ = self._run_blocks(params, x, aux, ctx, st, caches)
+            x, caches, _, _ = self._run_blocks(params, x, aux, ctx, st,
+                                                  caches)
         logits = tf.lm_logits(params, x, ctx, cfg)
         return logits[:, 0], caches
 
@@ -464,6 +512,13 @@ class Model:
                 tree, is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
             return sds, _prepend(specs)
 
+        if fam == "moe" and cfg.first_k_dense:
+            sh = tf.attn_cache_shape(cfg, ctx, batch_local, cache_len_local)
+            parts = {k: stacked(sh, kv_specs(), n) for k, n in (
+                ("dense_blocks", cfg.first_k_dense),
+                ("moe_blocks", cfg.n_layers - cfg.first_k_dense))}
+            return ({k: v[0] for k, v in parts.items()},
+                    {k: v[1] for k, v in parts.items()})
         if fam in ("dense", "vlm", "moe"):
             sh = tf.attn_cache_shape(cfg, ctx, batch_local, cache_len_local)
             return stacked(sh, kv_specs(), cfg.n_layers)

@@ -1,7 +1,24 @@
-"""Mixture-of-Experts FFN with expert parallelism (DESIGN.md §4/§5).
+"""Mixture-of-Experts FFN: the capacity layer with expert parallelism
+(``moe_apply``) and the drop-free layer (``dropfree_apply``).
 
-Routing is top-k softmax with a fixed per-expert capacity (dropped tokens
-fall back to the residual path).  Dispatch is sort-based — O(T·k) memory, no
+The drop-free layer (DeepSeek-V3 routing, ``MoEConfig.drop_free``) routes
+every token over all ``n_experts`` in float32 (sigmoid scores; the top-k of
+score + correction bias chosen; the chosen scores, renormalised, times
+``routed_scale`` weight them).  It
+holds experts ``[first_held, first_held + n_held)``, a chip's share of an
+expert-parallel layer, and computes every token-slot routed to them with no
+capacity: the slots are sorted by expert into a row buffer, each expert's
+rows padded to whole tiles, and a grouped matmul runs over the tiles that
+hold rows (``kernels/ops.grouped_matmul``: the Pallas kernel
+``kernels/gmm.py`` on TPU).  Slots routed to experts held elsewhere add
+nothing here; on one chip there is no exchange.  Shared experts are one
+SwiGLU of ``n_shared`` experts' width, gated by a sigmoid only where
+``shared_gate``.  The layer returns counters: slots routed to held experts,
+those of them the grouped matmul left uncomputed (0), and the largest load
+over the mean among all ``n_experts``.
+
+The capacity layer: routing is top-k softmax with a fixed per-expert
+capacity (dropped tokens fall back to the residual path).  Dispatch is sort-based — O(T·k) memory, no
 (T, E, C) one-hot tensor — which is what makes the 32k-token train shapes
 fit:
 
@@ -91,15 +108,35 @@ def moe_init(key, cfg, ctx: ShardCtx, ep: Optional[int] = None):
     }
     specs = {"router": P(None, None), "experts": expert_specs}
     if mc.n_shared:
-        ps, ss = mlp_init(ks[2], d, d_ff * mc.n_shared, ctx)
-        params["shared"], specs["shared"] = ps, ss
-        params["shared_gate"] = _trunc_normal(ks[4], (d, 1), 0.02,
-                                              jnp.float32)
-        specs["shared_gate"] = P(None, None)
+        ps, ss = shared_init(ks[2], ks[4], cfg, ctx, d_ff * mc.n_shared)
+        params.update(ps)
+        specs.update(ss)
     if mc.dense_residual:
         pd, sd = mlp_init(ks[3], d, d_ff, ctx)
         params["dense"], specs["dense"] = pd, sd
     return params, specs
+
+
+def shared_init(k_mlp, k_gate, cfg, ctx: ShardCtx, width: int):
+    """Shared experts: one SwiGLU of ``width``, with a sigmoid gate on its
+    output where ``moe.shared_gate`` (qwen2-moe; DeepSeek-V3 has none)."""
+    params, specs = {}, {}
+    params["shared"], specs["shared"] = mlp_init(k_mlp, cfg.d_model, width,
+                                                 ctx)
+    if cfg.moe.shared_gate:
+        params["shared_gate"] = _trunc_normal(k_gate, (cfg.d_model, 1), 0.02,
+                                              jnp.float32)
+        specs["shared_gate"] = P(None, None)
+    return params, specs
+
+
+def shared_apply(params, x, ctx: ShardCtx, cfg):
+    sh = mlp_apply(params["shared"], x, ctx)
+    if not cfg.moe.shared_gate:
+        return sh
+    gate = jax.nn.sigmoid(
+        x.astype(jnp.float32) @ maybe_tp_shared(params["shared_gate"], ctx))
+    return sh * gate.astype(x.dtype)
 
 
 def _route(router_w, x, mc, e_pad: int):
@@ -212,11 +249,7 @@ def moe_apply(params, x, ctx: ShardCtx, cfg, ep_axis: Optional[str] = None):
 
     # ---- shared experts / dense residual (plain TP MLPs) ----
     if mc.n_shared:
-        sh = mlp_apply(params["shared"], x, ctx)
-        gate = jax.nn.sigmoid(
-            x.astype(jnp.float32) @ maybe_tp_shared(params["shared_gate"],
-                                                    ctx))
-        y = y + sh * gate.astype(x.dtype)
+        y = y + shared_apply(params, x, ctx, cfg)
     if mc.dense_residual:
         y = y + mlp_apply(params["dense"], x, ctx)
     return y, _aux_loss(logits, top_i, mc, e_pad)
@@ -232,13 +265,215 @@ def _aux_loss(logits, top_i, mc, e_pad: int):
 
 
 # --------------------------------------------------------------------------
+# drop-free layer
+# --------------------------------------------------------------------------
+#: rows of a grouped-matmul tile: each held expert's rows are padded to
+#: whole tiles
+TILE_ROWS = 256
+#: the counters that merge by their maximum; the others add up
+STAT_MAX = ("load_max_over_mean",)
+
+
+def merge_stats(a: dict, b: dict) -> dict:
+    """Counters of two layers (or microbatches); a layer that keeps none
+    gives an empty dict."""
+    out = {**a, **b}
+    out.update({k: (jnp.maximum if k in STAT_MAX else jnp.add)(a[k], b[k])
+                for k in a.keys() & b.keys()})
+    return out
+
+
+def total_stats(stacked: dict) -> dict:
+    """Counters stacked over layers (a scan's output) merged into one."""
+    return {k: (jnp.max if k in STAT_MAX else jnp.sum)(v)
+            for k, v in stacked.items()}
+
+
+def reduce_stats(stats: dict, axes) -> dict:
+    """Counters merged over the mesh ``axes`` (none: as they are)."""
+    if not axes:
+        return stats
+    return {k: (jax.lax.pmax if k in STAT_MAX else jax.lax.psum)(v, axes)
+            for k, v in stats.items()}
+
+
+def dropfree_init(key, cfg, ctx: ShardCtx):
+    """Router over all ``n_experts``, the held experts stacked (n_held, ..)
+    and the shared experts.  On the training mesh a chip holds its share
+    whole (no expert axis)."""
+    mc = cfg.moe
+    d, f, held = cfg.d_model, mc.d_expert, mc.held
+    ks = jax.random.split(key, 6)
+    dt = ctx.param_dtype
+    params = {
+        "router": _trunc_normal(ks[0], (d, mc.n_experts), 0.02, dt),
+        "experts": {
+            "gate": _trunc_normal(ks[1], (held, d, f), 1 / math.sqrt(d), dt),
+            "up": _trunc_normal(ks[2], (held, d, f), 1 / math.sqrt(d), dt),
+            "down": _trunc_normal(ks[3], (held, f, d), 1 / math.sqrt(f),
+                                  dt)},
+    }
+    specs = {"router": P(None, None),
+             "experts": {k: P(None, None, None)
+                         for k in ("gate", "up", "down")}}
+    if mc.n_shared:
+        ps, ss = shared_init(ks[4], ks[5], cfg, ctx, f * mc.n_shared)
+        params.update(ps)
+        specs.update(ss)
+    return params, specs
+
+
+def route(logits, mc, bias):
+    """Float32 logits (T, n_experts) -> (weights (T, k), experts (T, k)):
+    the top-k of sigmoid score + ``bias`` (selection only), weighted by
+    their scores renormalised to sum 1, times ``mc.routed_scale``."""
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(jax.lax.stop_gradient(scores + bias), mc.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    return w * mc.routed_scale, idx.astype(jnp.int32)
+
+
+def dispatch_plan(idx, mc, tm: int):
+    """Where each token-slot routed to a held expert goes in the row buffer.
+
+    Returns ``(slot_row (T, k), row_tok (M,), row_slot (M,), group_tiles
+    (n_held,))``: a slot's row (``M`` where its expert is held elsewhere),
+    each row's token (``T`` for a padding row) and slot (``T * k``), and the
+    tiles each held expert's rows fill.  ``M`` rows hold the worst case,
+    every token's slots on held experts plus a part tile per expert."""
+    t, k = idx.shape
+    held, n = mc.held, idx.size
+    m = -(-(t * min(k, held) + held * (tm - 1)) // tm) * tm
+    local = idx.reshape(-1) - mc.first_held
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    counts = jnp.bincount(key, length=held + 1)
+    tiles = -(-counts[:held] // tm)
+    first_row = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                 jnp.cumsum(tiles * tm)])
+    order = jnp.argsort(key, stable=True)
+    sorted_key = key[order]
+    rank = jnp.arange(n) - (jnp.cumsum(counts) - counts)[sorted_key]
+    rows = jnp.where(sorted_key < held, first_row[sorted_key] + rank, m)
+    slot_row = jnp.zeros((n,), jnp.int32).at[order].set(rows.astype(
+        jnp.int32))
+    row_tok = jnp.full((m,), t, jnp.int32).at[slot_row].set(
+        jnp.arange(n, dtype=jnp.int32) // k, mode="drop")
+    row_slot = jnp.full((m,), n, jnp.int32).at[slot_row].set(
+        jnp.arange(n, dtype=jnp.int32), mode="drop")
+    return (slot_row.reshape(t, k), row_tok, row_slot,
+            tiles.astype(jnp.int32))
+
+
+def slot_counts(idx, row_tok, tiles, mc, tm: int):
+    """(token-slots routed to the held experts, those of them left
+    uncomputed): the first counted from ``idx`` (T, k), the second less the
+    rows of the tiles the grouped matmul runs that hold a token."""
+    local = idx - mc.first_held
+    held = jnp.sum((local >= 0) & (local < mc.held))
+    run = jnp.arange(row_tok.shape[0]) < jnp.sum(tiles) * tm
+    computed = jnp.sum((row_tok < idx.shape[0]) & run)
+    return held, held - computed
+
+
+def _pad_row(x):
+    return jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)])
+
+
+@jax.custom_vjp
+def _to_rows(x, slot_row, row_tok):
+    """Token rows (T, d) to the row buffer (M, d); padding rows zero."""
+    return _pad_row(x)[row_tok]
+
+
+def _to_rows_fwd(x, slot_row, row_tok):
+    return _to_rows(x, slot_row, row_tok), (slot_row, row_tok)
+
+
+def _to_rows_bwd(res, g):
+    slot_row, row_tok = res
+    dx = jnp.sum(_pad_row(g)[slot_row].astype(jnp.float32), axis=1)
+    return dx.astype(g.dtype), None, None
+
+
+_to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
+
+
+@jax.custom_vjp
+def _to_tokens(y, w, slot_row, row_tok, row_slot):
+    """Row buffer (M, d) to tokens (T, d): each token's held slots,
+    weighted by ``w`` (T, k), summed in float32."""
+    g = _pad_row(y)[slot_row].astype(jnp.float32)          # (T, k, d)
+    return jnp.einsum("tkd,tk->td", g, w).astype(y.dtype)
+
+
+def _to_tokens_fwd(y, w, slot_row, row_tok, row_slot):
+    return _to_tokens(y, w, slot_row, row_tok, row_slot), \
+        (y, w, slot_row, row_tok, row_slot)
+
+
+def _to_tokens_bwd(res, dy):
+    y, w, slot_row, row_tok, row_slot = res
+    w_row = _pad_row(w.reshape(-1))[row_slot]
+    d_rows = (_pad_row(dy)[row_tok].astype(jnp.float32)
+              * w_row[:, None]).astype(y.dtype)
+    g = _pad_row(y)[slot_row].astype(jnp.float32)
+    dw = jnp.einsum("tkd,td->tk", g, dy.astype(jnp.float32))
+    return d_rows, dw, None, None, None
+
+
+_to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
+
+
+def dropfree_apply(params, x, ctx: ShardCtx, cfg, tm: int = TILE_ROWS):
+    """x: (B, S, d) -> (routed + shared output (B, S, d), counters).
+    The correction bias is a zero buffer (its balancing update is not
+    trained here)."""
+    from repro.kernels import ops
+    mc = cfg.moe
+    if ctx.tp != 1:
+        raise NotImplementedError("the drop-free layer under tensor "
+                                  "parallelism")
+    b, s, d = x.shape
+    t = b * s
+    xt = x.reshape(t, d)
+    cd = ctx.compute_dtype
+    with jax.named_scope("moe"):
+        logits = xt.astype(jnp.float32) @ params["router"].astype(
+            jnp.float32)
+        w, idx = route(logits, mc, jnp.zeros((mc.n_experts,), jnp.float32))
+        slot_row, row_tok, row_slot, tiles = dispatch_plan(idx, mc, tm)
+        rows = _to_rows(xt.astype(cd), slot_row, row_tok)
+    ex = params["experts"]
+
+    def gm(a, name):
+        return ops.grouped_matmul(a, ex[name].astype(cd), tiles, tm=tm)
+
+    h = jax.nn.silu(gm(rows, "gate")) * gm(rows, "up")
+    out_rows = gm(h, "down")
+    with jax.named_scope("moe"):
+        y = _to_tokens(out_rows, w, slot_row, row_tok, row_slot)
+        load = jnp.bincount(idx.reshape(-1), length=mc.n_experts)
+        held, dropped = slot_counts(idx, row_tok, tiles, mc, tm)
+        stats = {
+            "held_slots": held, "dropped_slots": dropped,
+            "load_max_over_mean": jnp.max(load).astype(jnp.float32)
+            / (idx.size / mc.n_experts)}
+    y = y.reshape(b, s, d).astype(x.dtype)
+    if mc.n_shared:
+        y = y + shared_apply(params, x, ctx, cfg)
+    return y, stats
+
+
+# --------------------------------------------------------------------------
 # MoE transformer block (attention + MoE FFN)
 # --------------------------------------------------------------------------
 def moe_block_init(key, cfg, ctx: ShardCtx):
-    from repro.models.transformer import attn_init, rmsnorm_init
+    from repro.models.transformer import attention_init, rmsnorm_init
     ks = jax.random.split(key, 4)
-    pa, sa = attn_init(ks[0], cfg, ctx)
-    pm, sm = moe_init(ks[1], cfg, ctx)
+    pa, sa = attention_init(ks[0], cfg, ctx)
+    pm, sm = (dropfree_init if cfg.moe.drop_free else moe_init)(
+        ks[1], cfg, ctx)
     pn1, sn1 = rmsnorm_init(cfg.d_model, ctx)
     pn2, sn2 = rmsnorm_init(cfg.d_model, ctx)
     return ({"attn": pa, "moe": pm, "ln1": pn1, "ln2": pn2},
@@ -246,12 +481,17 @@ def moe_block_init(key, cfg, ctx: ShardCtx):
 
 
 def moe_block_apply(params, x, aux, ctx: ShardCtx, cfg, st, cache=None):
+    """Returns (x, cache, aux loss, counters): the capacity layer's aux loss
+    and no counters, or the drop-free layer's counters and aux loss 0."""
     from repro.models.layers import rmsnorm
-    from repro.models.transformer import attn_apply
-    a, cache = attn_apply(params["attn"],
-                          rmsnorm(params["ln1"], x, cfg.norm_eps),
-                          aux, ctx, cfg, st, cache)
+    from repro.models.transformer import attention_apply
+    a, cache = attention_apply(params["attn"],
+                               rmsnorm(params["ln1"], x, cfg.norm_eps),
+                               aux, ctx, cfg, st, cache)
     x = x + a
-    m, aux_loss = moe_apply(params["moe"],
-                            rmsnorm(params["ln2"], x, cfg.norm_eps), ctx, cfg)
-    return x + m, cache, aux_loss
+    h = rmsnorm(params["ln2"], x, cfg.norm_eps)
+    if cfg.moe.drop_free:
+        m, stats = dropfree_apply(params["moe"], h, ctx, cfg)
+        return x + m, cache, jnp.float32(0.0), stats
+    m, aux_loss = moe_apply(params["moe"], h, ctx, cfg)
+    return x + m, cache, aux_loss, {}

@@ -24,8 +24,9 @@ def _counts(cfg) -> tuple[int, int]:
     total = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(shapes))
     routed = 0
     if cfg.moe.n_experts:
-        # experts subtree: blocks/moe/experts {gate, up, down}
-        sub = shapes["blocks"]["moe"]["experts"]
+        # experts subtree: {moe_,}blocks/moe/experts {gate, up, down}
+        stack = "moe_blocks" if cfg.first_k_dense else "blocks"
+        sub = shapes[stack]["moe"]["experts"]
         routed = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(sub))
     return total, routed
 

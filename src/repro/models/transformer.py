@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models import attention as attn_ops
+from repro.models import mla
 from repro.models.layers import (ShardCtx, TP_AXIS, apply_mrope, apply_rope,
                                  column_linear, column_linear_init,
                                  embedding_lookup, embedding_init,
@@ -256,6 +257,8 @@ def attn_cache_shape(cfg, ctx: ShardCtx, batch_local: int,
                      cache_len_local: int, dtype=jnp.bfloat16):
     """Per-layer KV cache (LOCAL shapes inside shard_map; the caller divides
     cache_len by the context-parallel degree when ctx.cache_seq_axes)."""
+    if cfg.mla is not None:
+        return mla.mla_cache_shape(cfg, batch_local, cache_len_local, dtype)
     lay = head_layout(cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, ctx.tp)
     return {"k": jax.ShapeDtypeStruct(
                 (batch_local, cache_len_local, lay.kv_local, lay.head_dim),
@@ -268,9 +271,24 @@ def attn_cache_shape(cfg, ctx: ShardCtx, batch_local: int,
 # --------------------------------------------------------------------------
 # Dense block = pre-norm attn + pre-norm MLP
 # --------------------------------------------------------------------------
+def attention_init(key, cfg, ctx: ShardCtx):
+    """The attention sub-block's weights: latent (MLA) where the config
+    has ``mla``, GQA otherwise."""
+    if cfg.mla is not None:
+        return mla.mla_init(key, cfg, ctx)
+    return attn_init(key, cfg, ctx)
+
+
+def attention_apply(params, x, aux: Aux, ctx: ShardCtx, cfg,
+                    st: StepState, cache=None):
+    if cfg.mla is not None:
+        return mla.mla_apply(params, x, aux, ctx, cfg, st, cache)
+    return attn_apply(params, x, aux, ctx, cfg, st, cache)
+
+
 def dense_block_init(key, cfg, ctx: ShardCtx):
     ks = jax.random.split(key, 4)
-    pa, sa = attn_init(ks[0], cfg, ctx)
+    pa, sa = attention_init(ks[0], cfg, ctx)
     pm, sm = mlp_init(ks[1], cfg.d_model, cfg.d_ff, ctx)
     pn1, sn1 = rmsnorm_init(cfg.d_model, ctx)
     pn2, sn2 = rmsnorm_init(cfg.d_model, ctx)
@@ -280,9 +298,9 @@ def dense_block_init(key, cfg, ctx: ShardCtx):
 
 def dense_block_apply(params, x, aux: Aux, ctx: ShardCtx, cfg, st: StepState,
                       cache=None):
-    a, cache = attn_apply(params["attn"], rmsnorm(params["ln1"], x,
-                                                  cfg.norm_eps),
-                          aux, ctx, cfg, st, cache)
+    a, cache = attention_apply(params["attn"],
+                               rmsnorm(params["ln1"], x, cfg.norm_eps),
+                               aux, ctx, cfg, st, cache)
     x = x + a
     x = x + mlp_apply(params["mlp"], rmsnorm(params["ln2"], x, cfg.norm_eps),
                       ctx)

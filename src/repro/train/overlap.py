@@ -46,9 +46,12 @@ the paper's claim, made executable.
 
 Supported workload matrix (see docs/overlap.md for the decision table):
 
-  * every model family — dense/vlm/moe (``params["blocks"]``),
-    hybrid/ssm (``params["groups"]``), and the enc-dec audio family
-    (``params["dec_blocks"]`` + ``params["enc_blocks"]``);
+  * every model family — dense/vlm/moe (``params["blocks"]``), moe with a
+    leading dense stack (``params["dense_blocks"]`` then
+    ``params["moe_blocks"]``, segmented in backward order: the MoE stack,
+    then the dense stack), hybrid/ssm (``params["groups"]``), and the
+    enc-dec audio family (``params["dec_blocks"]`` +
+    ``params["enc_blocks"]``);
   * ``zero1=True`` — optimizer state owner-sharded along the leaf-aligned
     bucket boundaries (``train_step.zero1_apply``: flat AdamW on the
     owned shard, params all-gathered through the Payload reduce
@@ -73,6 +76,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import aggregator as agg_mod
 from repro.core import bucketing
+from repro.models import moe as moe_mod
 
 #: libtpu flags that let the latency-hiding scheduler overlap the pinned
 #: collectives with backward compute.  They belong in ``LIBTPU_INIT_ARGS``,
@@ -84,17 +88,17 @@ TPU_OVERLAP_FLAGS = (
     "--xla_tpu_enable_async_collective_fusion=true "
     "--xla_tpu_enable_async_collective_fusion_fuse_all_reduce=true")
 
-#: families whose training stack is a single scanned block collection.
-_STACK_KEYS = {"dense": "blocks", "vlm": "blocks", "moe": "blocks",
-               "hybrid": "groups", "ssm": "groups"}
+#: families whose training stacks the step segments
+_FAMILIES = ("dense", "vlm", "moe", "hybrid", "ssm", "audio")
 
 
-def _stack_keys(family: str) -> tuple[str, ...]:
-    """The scanned param collections of a family, in BACKWARD-COMPLETION
-    order (enc-dec: decoder grads are final before the encoder's)."""
-    if family == "audio":
-        return ("dec_blocks", "enc_blocks")
-    return (_STACK_KEYS[family],)
+def _stack_keys(arch) -> tuple[str, ...]:
+    """The scanned param collections of a model, in BACKWARD-COMPLETION
+    order (the reverse of ``models.model.stack_keys``: enc-dec decoder
+    grads are final before the encoder's, MoE blocks' before the leading
+    dense blocks')."""
+    from repro.models.model import stack_keys
+    return stack_keys(arch)[::-1]
 
 
 def enable_overlap_flags() -> None:
@@ -118,7 +122,7 @@ def supports(arch, plan) -> tuple[bool, str]:
         return False, ("overlap interleaves DDP bucket collectives; FSDP's "
                        "per-layer reduce-scatter already overlaps via the "
                        "all_gather AD transpose")
-    if arch.family not in _STACK_KEYS and arch.family != "audio":
+    if arch.family not in _FAMILIES:
         return False, f"family {arch.family!r} has no scanned block " \
                       "stack to segment"
     return True, ""
@@ -221,7 +225,7 @@ def build_layout(setup) -> OverlapLayout:
         return cached[1]
     check_supported(setup.arch, setup.arch.plan)
     grads_like = ts._grads_like_local(setup)
-    keys = _stack_keys(setup.arch.family)
+    keys = _stack_keys(setup.arch)
     rest, stacks_p = _split_params(grads_like, keys)
     segs: list[StackSeg] = []
     leaf_sizes: list[int] = []
@@ -355,10 +359,12 @@ def _make_aux(batch):
 
 
 def _stage_fns(setup, batch, xent_chunk: int):
-    """(f_in, block, f_out, has_aux, has_shared) — each block stage is the
-    exact remat-wrapped body the serial scan runs, so the segmented
-    backward reproduces the scanned backward's math."""
-    from repro.models import moe as moe_mod
+    """(f_in, blocks, f_out, has_aux, has_shared) — ``blocks`` maps each
+    stack's key to its block: the exact remat-wrapped body the serial scan
+    runs (so the segmented backward reproduces the scanned backward's
+    math), returning ``(out, counters)``.  ``out`` is ``(y, aux loss)``
+    where ``has_aux`` (the capacity MoE layer), else ``y``; the counters
+    (the drop-free MoE layer's; empty elsewhere) are not differentiated."""
     from repro.models import transformer as tf
     from repro.models.model import _remat
     from repro.models.transformer import StepState
@@ -368,7 +374,7 @@ def _stage_fns(setup, batch, xent_chunk: int):
     remat = cfg.plan.remat
     aux = _make_aux(batch)
     fam = model.family
-    has_aux = fam == "moe"
+    has_aux = fam == "moe" and not cfg.moe.drop_free
     has_shared = fam == "hybrid"
 
     def f_in(p_rest):
@@ -377,40 +383,44 @@ def _stage_fns(setup, batch, xent_chunk: int):
                 batch["embeds"].astype(ctx.compute_dtype), ctx)
         return tf.embed_tokens(p_rest, batch["tokens"], ctx, cfg)
 
-    if fam in ("dense", "vlm"):
-        fn = partial(tf.dense_block_apply, aux=aux, ctx=ctx, cfg=cfg, st=st)
-
-        def block(p_l, x):
-            y, _ = _remat(fn, remat)(p_l, x, cache=None)
-            return y
-    elif fam == "moe":
-        fn = partial(moe_mod.moe_block_apply, aux=aux, ctx=ctx, cfg=cfg,
-                     st=st)
-
-        def block(p_l, x):
-            y, _, al = _remat(fn, remat)(p_l, x, cache=None)
-            return y, al
-    elif fam == "hybrid":
-        def block(p_g, shared, x):
-            fn = partial(model._zamba_group_apply, shared=shared, aux=aux,
-                         ctx=ctx, st=st, remat=remat)
-            y, _ = _remat(fn, remat)(p_g, x, cache=None)
-            return y
-    elif fam == "ssm":
-        def block(p_g, x):
-            fn = partial(model._xlstm_group_apply, ctx=ctx, st=st,
-                         remat=remat)
-            y, _ = _remat(fn, remat)(p_g, x, cache=None)
-            return y
-    else:  # pragma: no cover — check_supported gates
-        raise ValueError(fam)
-
     def f_out(p_rest, x):
         loss_sum, n_tok = tf.lm_loss(p_rest, x, batch["labels"], ctx, cfg,
                                      xent_chunk)
         return loss_sum, n_tok
 
-    return f_in, block, f_out, has_aux, has_shared
+    def dense_block(p_l, x):
+        fn = partial(tf.dense_block_apply, aux=aux, ctx=ctx, cfg=cfg, st=st)
+        y, _ = _remat(fn, remat)(p_l, x, cache=None)
+        return y, {}
+
+    if fam in ("dense", "vlm"):
+        block = dense_block
+    elif fam == "moe":
+        fn = partial(moe_mod.moe_block_apply, aux=aux, ctx=ctx, cfg=cfg,
+                     st=st)
+
+        def block(p_l, x):
+            y, _, al, stats = _remat(fn, remat)(p_l, x, cache=None)
+            return ((y, al) if has_aux else y), stats
+        if cfg.first_k_dense:
+            return f_in, {"dense_blocks": dense_block, "moe_blocks": block}, \
+                f_out, has_aux, has_shared
+    elif fam == "hybrid":
+        def block(p_g, shared, x):
+            fn = partial(model._zamba_group_apply, shared=shared, aux=aux,
+                         ctx=ctx, st=st, remat=remat)
+            y, _ = _remat(fn, remat)(p_g, x, cache=None)
+            return y, {}
+    elif fam == "ssm":
+        def block(p_g, x):
+            fn = partial(model._xlstm_group_apply, ctx=ctx, st=st,
+                         remat=remat)
+            y, _ = _remat(fn, remat)(p_g, x, cache=None)
+            return y, {}
+    else:  # pragma: no cover — check_supported gates
+        raise ValueError(fam)
+
+    return f_in, {_stack_keys(cfg)[0]: block}, f_out, has_aux, has_shared
 
 
 def _encdec_fns(setup, batch, xent_chunk: int):
@@ -478,37 +488,40 @@ def _backward_seed(setup, loss_sum, ntok):
 
 def _backward_stack(setup, ov: OverlapLayout, params, batch, flush: _Flush,
                     xent_chunk: int):
-    """Single-stack families: forward saves one vjp closure per block,
-    backward replays them in reverse layer order, flushing ready
-    buckets."""
+    """Decoder-only families: forward saves one vjp closure per block
+    (the stacks in forward order), backward replays them in reverse layer
+    order, stack by stack in backward order, flushing ready buckets."""
     from repro.train.train_step import MOE_AUX_COEF
 
-    f_in, block, f_out, has_aux, has_shared = _stage_fns(setup, batch,
-                                                         xent_chunk)
-    seg = ov.stacks[0]
-    L = seg.n_layers
-    p_rest, (stacked,) = _split_params(params, (seg.key,))
+    f_in, blocks, f_out, has_aux, has_shared = _stage_fns(setup, batch,
+                                                          xent_chunk)
+    fwd_segs = ov.stacks[::-1]
+    p_rest, stacks_p = _split_params(params, tuple(g.key for g in fwd_segs))
 
     # ---- forward: one vjp closure per block stage --------------------
     x, vjp_in = jax.vjp(f_in, p_rest)
-    block_vjps = []
+    block_vjps = {}
     aux_vals = []
-    for l in range(L):
-        p_l = jax.tree.map(lambda t, _l=l: t[_l], stacked)
-        if has_shared:
-            out, vjp_l = jax.vjp(block, p_l, p_rest["shared"], x)
-        else:
-            out, vjp_l = jax.vjp(block, p_l, x)
-        if has_aux:
-            x, al = out
-            aux_vals.append(al)
-        else:
-            x = out
-        block_vjps.append(vjp_l)
+    stats = {}
+    for seg, stacked in zip(fwd_segs, stacks_p):
+        block = blocks[seg.key]
+        block_vjps[seg.key] = []
+        for l in range(seg.n_layers):
+            p_l = jax.tree.map(lambda t, _l=l: t[_l], stacked)
+            args = (p_l, p_rest["shared"], x) if has_shared else (p_l, x)
+            out, vjp_l, c = jax.vjp(block, *args, has_aux=True)
+            stats = moe_mod.merge_stats(stats, c)
+            if has_aux:
+                x, al = out
+                aux_vals.append(al)
+            else:
+                x = out
+            block_vjps[seg.key].append(vjp_l)
     loss_sum, vjp_out, ntok = jax.vjp(f_out, p_rest, x, has_aux=True)
 
     # ---- backward seeds ---------------------------------------------
     seed = _backward_seed(setup, loss_sum, ntok)
+    L = len(aux_vals)
     moe_aux = (sum(aux_vals) / L) if has_aux else jnp.float32(0.0)
     aux_seed = jnp.asarray(MOE_AUX_COEF / (L * setup.p_fsdp),
                            aux_vals[0].dtype) if has_aux else None
@@ -516,16 +529,17 @@ def _backward_stack(setup, ov: OverlapLayout, params, batch, flush: _Flush,
     # ---- backward: reverse layer order, flushing ready buckets -------
     d_rest_out, d_x = vjp_out(seed)
     shared_acc = None
-    for s in range(L):
-        l = L - 1 - s
-        cot = (d_x, aux_seed) if has_aux else d_x
-        if has_shared:
-            d_pl, d_sh, d_x = block_vjps[l](cot)
-            shared_acc = d_sh if shared_acc is None else \
-                jax.tree.map(jnp.add, shared_acc, d_sh)
-        else:
-            d_pl, d_x = block_vjps[l](cot)
-        d_x = flush.stage(s, d_pl, d_x)
+    for seg in ov.stacks:
+        for s in range(seg.n_layers):
+            l = seg.n_layers - 1 - s
+            cot = (d_x, aux_seed) if has_aux else d_x
+            if has_shared:
+                d_pl, d_sh, d_x = block_vjps[seg.key][l](cot)
+                shared_acc = d_sh if shared_acc is None else \
+                    jax.tree.map(jnp.add, shared_acc, d_sh)
+            else:
+                d_pl, d_x = block_vjps[seg.key][l](cot)
+            d_x = flush.stage(seg.stage0 + s, d_pl, d_x)
 
     d_rest_in, = vjp_in(d_x)
     grads_rest = jax.tree.map(jnp.add, d_rest_out, d_rest_in)
@@ -534,7 +548,7 @@ def _backward_stack(setup, ov: OverlapLayout, params, batch, flush: _Flush,
                       "shared": jax.tree.map(jnp.add, grads_rest["shared"],
                                              shared_acc)}
     grads = flush.tail(jax.tree_util.tree_leaves(grads_rest), params)
-    return grads, loss_sum, ntok, moe_aux
+    return grads, loss_sum, ntok, moe_aux, stats
 
 
 def _backward_encdec(setup, ov: OverlapLayout, params, batch, flush: _Flush,
@@ -582,7 +596,7 @@ def _backward_encdec(setup, ov: OverlapLayout, params, batch, flush: _Flush,
     grads_rest = jax.tree.map(lambda a, b, c: a + b + c,
                               d_rest_out, d_rest_in, d_rest_mem)
     grads = flush.tail(jax.tree_util.tree_leaves(grads_rest), params)
-    return grads, loss_sum, ntok, jnp.float32(0.0)
+    return grads, loss_sum, ntok, jnp.float32(0.0), {}
 
 
 def _segmented_backward(setup, ov: OverlapLayout, params, batch,
@@ -590,7 +604,7 @@ def _segmented_backward(setup, ov: OverlapLayout, params, batch,
                         acc=None, inv_accum=None):
     """Forward (per-block vjp closures) + reverse-order backward with
     per-bucket aggregation.  Returns (grads, new_agg_states, loss_sum,
-    ntok, moe_aux).  ``schedule="overlap"`` flushes each completed bucket
+    ntok, moe_aux, moe_stats).  ``schedule="overlap"`` flushes each completed bucket
     between backward stages, barrier-pinned; ``"serial"`` flushes all
     buckets after the full backward.  Values are bit-identical.
     ``schedule="raw"`` skips aggregation entirely and returns the local
@@ -604,12 +618,12 @@ def _segmented_backward(setup, ov: OverlapLayout, params, batch,
     once, on the final microbatch, still in reverse layer order."""
     flush = _Flush(setup, ov, agg_states, schedule, acc, inv_accum)
     if setup.arch.family == "audio":
-        grads, loss_sum, ntok, moe_aux = _backward_encdec(
+        grads, loss_sum, ntok, moe_aux, stats = _backward_encdec(
             setup, ov, params, batch, flush, xent_chunk)
     else:
-        grads, loss_sum, ntok, moe_aux = _backward_stack(
+        grads, loss_sum, ntok, moe_aux, stats = _backward_stack(
             setup, ov, params, batch, flush, xent_chunk)
-    return grads, flush.new_agg(agg_states), loss_sum, ntok, moe_aux
+    return grads, flush.new_agg(agg_states), loss_sum, ntok, moe_aux, stats
 
 
 def make_step(setup, schedule: str = "overlap", accum: int = 1,
@@ -644,10 +658,8 @@ def make_step(setup, schedule: str = "overlap", accum: int = 1,
 
     def backward(state, params, batch):
         if accum == 1:
-            grads, new_agg, loss_sum, ntok, aux = _segmented_backward(
-                setup, ov, params, batch, state["agg"], schedule,
-                xent_chunk)
-            return grads, new_agg, loss_sum, ntok, aux
+            return _segmented_backward(setup, ov, params, batch,
+                                       state["agg"], schedule, xent_chunk)
         b_local = jax.tree_util.tree_leaves(batch)[0].shape[0]
         if b_local % accum:
             raise ValueError(
@@ -661,9 +673,10 @@ def make_step(setup, schedule: str = "overlap", accum: int = 1,
         loss_sum = jnp.float32(0.0)
         ntok = None
         aux = jnp.float32(0.0)
+        stats = {}
         for m in range(accum - 1):
             mb = jax.tree.map(lambda x, _m=m: x[_m], mbs)
-            g_m, _, l_m, n_m, a_m = _segmented_backward(
+            g_m, _, l_m, n_m, a_m, s_m = _segmented_backward(
                 setup, ov, params, mb, (), "raw", xent_chunk)
             ordered = [v.astype(jnp.float32)
                        for v in _ordered_leaves(ov, g_m)]
@@ -672,19 +685,21 @@ def make_step(setup, schedule: str = "overlap", accum: int = 1,
             loss_sum = loss_sum + l_m
             ntok = n_m if ntok is None else ntok + n_m
             aux = aux + a_m
+            stats = moe_mod.merge_stats(stats, s_m)
         mb = jax.tree.map(lambda x: x[accum - 1], mbs)
-        grads, new_agg, l_m, n_m, a_m = _segmented_backward(
+        grads, new_agg, l_m, n_m, a_m, s_m = _segmented_backward(
             setup, ov, params, mb, state["agg"], schedule, xent_chunk,
             acc=acc, inv_accum=1.0 / accum)
         return (grads, new_agg, loss_sum + l_m, ntok + n_m,
-                (aux + a_m) / accum)
+                (aux + a_m) / accum, moe_mod.merge_stats(stats, s_m))
 
     def step_fn(state, batch, lr):
         params = state["params"]
-        grads, new_agg, loss_sum, ntok, aux = backward(state, params, batch)
+        grads, new_agg, loss_sum, ntok, aux, stats = backward(state, params,
+                                                              batch)
         new_params, new_opt, gnorm = update_fn(params, grads,
                                                state["opt"], lr)
-        metrics = ts.train_metrics(setup, loss_sum, ntok, gnorm, aux)
+        metrics = ts.train_metrics(setup, loss_sum, ntok, gnorm, aux, stats)
         new_state = {"step": state["step"] + 1, "params": new_params,
                      "opt": new_opt, "agg": new_agg}
         return new_state, metrics
@@ -696,9 +711,7 @@ def make_step(setup, schedule: str = "overlap", accum: int = 1,
         bspecs = batch_spec_fn(batch_example)
         f = shard_map(step_fn, setup.mesh,
                       in_specs=(setup.state_specs, bspecs, P()),
-                      out_specs=(setup.state_specs,
-                                 {"loss": P(), "tokens": P(),
-                                  "grad_norm": P(), "moe_aux": P()}))
+                      out_specs=(setup.state_specs, P()))
         return jax.jit(f, donate_argnums=(0,))
 
     return jitted
@@ -724,16 +737,18 @@ def make_unfused_step(setup, xent_chunk: int = 1024):
     update_fn = ts.make_update_fn(setup, ov.layout, ov)
 
     def backward_fn(params, batch):
-        grads, _, loss_sum, ntok, aux = _segmented_backward(
+        grads, _, loss_sum, ntok, aux, stats = _segmented_backward(
             setup, ov, params, batch, (), "raw", xent_chunk)
         # leading device dim: raw grads differ per device pre-aggregation
-        return (jax.tree.map(lambda g: g[None], grads), loss_sum[None],
-                ntok[None], aux[None])
+        return jax.tree.map(lambda g: g[None],
+                            (grads, loss_sum, ntok, aux, stats))
 
-    def agg_update_fn(state, grads_dev, loss_dev, ntok_dev, aux_dev, lr):
+    def agg_update_fn(state, grads_dev, loss_dev, ntok_dev, aux_dev,
+                      stats_dev, lr):
         params = state["params"]
-        grads = jax.tree.map(lambda g: g[0], grads_dev)
-        loss_sum, ntok, aux = loss_dev[0], ntok_dev[0], aux_dev[0]
+        grads, loss_sum, ntok, aux, stats = jax.tree.map(
+            lambda g: g[0], (grads_dev, loss_dev, ntok_dev, aux_dev,
+                             stats_dev))
         aggregator = agg_mod.GradAggregator(setup.agg_cfg)
         if setup.rtob:
             # no bucket aggregation: the update's reduce-scatter is the
@@ -756,7 +771,7 @@ def make_unfused_step(setup, xent_chunk: int = 1024):
             new_agg = state["agg"]
         new_params, new_opt, gnorm = update_fn(params, grads,
                                                state["opt"], lr)
-        metrics = ts.train_metrics(setup, loss_sum, ntok, gnorm, aux)
+        metrics = ts.train_metrics(setup, loss_sum, ntok, gnorm, aux, stats)
         return {"step": state["step"] + 1, "params": new_params,
                 "opt": new_opt, "agg": new_agg}, metrics
 
@@ -769,20 +784,17 @@ def make_unfused_step(setup, xent_chunk: int = 1024):
         f1 = jax.jit(shard_map(
             backward_fn, setup.mesh,
             in_specs=(setup.state_specs["params"], bspecs),
-            out_specs=(gspecs, P(all_ax), P(all_ax), P(all_ax))))
+            out_specs=(gspecs, P(all_ax), P(all_ax), P(all_ax),
+                       P(all_ax))))
         f2 = jax.jit(shard_map(
             agg_update_fn, setup.mesh,
             in_specs=(setup.state_specs, gspecs, P(all_ax), P(all_ax),
-                      P(all_ax), P()),
-            out_specs=(setup.state_specs,
-                       {"loss": P(), "tokens": P(),
-                        "grad_norm": P(), "moe_aux": P()})),
+                      P(all_ax), P(all_ax), P()),
+            out_specs=(setup.state_specs, P())),
             donate_argnums=(0, 1))
 
         def step(state, batch, lr):
-            grads_dev, loss_dev, ntok_dev, aux_dev = f1(state["params"],
-                                                        batch)
-            return f2(state, grads_dev, loss_dev, ntok_dev, aux_dev, lr)
+            return f2(state, *f1(state["params"], batch), lr)
 
         return step
 

@@ -58,6 +58,7 @@ from repro.configs.base import ArchConfig
 from repro.core import aggregator as agg_mod
 from repro.core import bucketing
 from repro.models import Model
+from repro.models import moe as moe_mod
 from repro.models.layers import ShardCtx
 from repro.train import optimizer as opt_mod
 
@@ -538,19 +539,28 @@ def make_update_fn(setup: TrainSetup, layout, ov=None):
     return update
 
 
-def train_metrics(setup: TrainSetup, loss_sum, ntok, gnorm, moe_aux):
-    """The step's metrics dict (loss is the DP-global token mean)."""
+def train_metrics(setup: TrainSetup, loss_sum, ntok, gnorm, moe_aux,
+                  moe_stats):
+    """The step's metrics dict (loss is the DP-global token mean).  The
+    drop-free MoE layers' counters go under ``moe`` (empty elsewhere):
+    slots routed to the held experts and slots left uncomputed, summed over
+    layers and chips, and the largest load over the mean, the worst layer
+    of any chip."""
     dp = setup.dp_axes
     loss_g = jax.lax.psum(loss_sum, dp) if dp else loss_sum
     ntok_g = jax.lax.psum(ntok, dp) if dp else ntok
     return {"loss": loss_g / jnp.maximum(ntok_g.astype(jnp.float32), 1.0),
             "tokens": ntok_g,
             "grad_norm": gnorm,
-            "moe_aux": moe_aux}
+            "moe_aux": moe_aux,
+            "moe": moe_mod.reduce_stats(moe_stats, dp)}
 
 
 def _fill_zero1_master(setup: TrainSetup, state, layout):
-    """Initialize each rank's fp32 master from its owned param buckets."""
+    """Initialize each rank's fp32 master from its owned param buckets.
+    The old shard is donated, so ``m`` and ``v`` pass through in place: no
+    second copy of the fp32 moments is ever live (a chip filled to its
+    memory has no room for one)."""
     plan = _zero1_plan(setup)
     buckets_of, _ = _zero1_bucket_fns(setup, layout)
 
@@ -561,7 +571,8 @@ def _fill_zero1_master(setup: TrainSetup, state, layout):
     sspec = setup.state_specs["opt"]["shard"]
     f = shard_map(fill, setup.mesh, in_specs=(setup.param_specs, sspec),
                   out_specs=sspec)
-    new_shard = jax.jit(f)(state["params"], state["opt"]["shard"])
+    new_shard = jax.jit(f, donate_argnums=1)(state["params"],
+                                             state["opt"]["shard"])
     state["opt"] = {**state["opt"], "shard": new_shard}
     return state
 
@@ -587,12 +598,12 @@ def make_step(setup: TrainSetup, accum: int = 1, xent_chunk: int = 1024):
     scale_axes = p_dp // p_fsdp
 
     def loss_fn(params, batch):
-        loss_sum, ntok, moe_aux = model.loss(params, batch, ctx)
+        loss_sum, ntok, moe_aux, stats = model.loss(params, batch, ctx)
         n_glob = jax.lax.psum(ntok, dp) if dp else ntok
         scaled = loss_sum * (scale_axes / n_glob.astype(jnp.float32))
         if arch.moe.n_experts:
             scaled = scaled + MOE_AUX_COEF * moe_aux / p_fsdp
-        return scaled, (loss_sum, ntok, moe_aux)
+        return scaled, (loss_sum, ntok, moe_aux, stats)
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -643,29 +654,31 @@ def make_step(setup: TrainSetup, accum: int = 1, xent_chunk: int = 1024):
     update_fn = make_update_fn(setup, layout)
 
     def one_micro(params, batch):
-        (scaled, (loss_sum, ntok, aux)), grads = grad_fn(params, batch)
-        return grads, loss_sum, ntok, aux
+        (scaled, (loss_sum, ntok, aux, stats)), grads = grad_fn(params,
+                                                                batch)
+        return grads, loss_sum, ntok, aux, stats
 
     def step_fn(state, batch, lr):
         params = state["params"]
         if accum > 1:
             def micro(carry, mb):
                 g_acc, l_acc, n_acc, a_acc = carry
-                g, l, n, a = one_micro(params, mb)
+                g, l, n, a, st = one_micro(params, mb)
                 return (jax.tree.map(jnp.add, g_acc, g), l_acc + l,
-                        n_acc + n, a_acc + a), None
+                        n_acc + n, a_acc + a), st
             zeros = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params)
             mbs = jax.tree.map(
                 lambda x: x.reshape((accum, x.shape[0] // accum)
                                     + x.shape[1:]), batch)
-            (grads, loss_sum, ntok, aux), _ = jax.lax.scan(
+            (grads, loss_sum, ntok, aux), stats = jax.lax.scan(
                 micro, (zeros, jnp.float32(0), jnp.int32(0),
                         jnp.float32(0)), mbs)
             grads = jax.tree.map(lambda g: g / accum, grads)
             aux = aux / accum
+            stats = moe_mod.total_stats(stats)
         else:
-            grads, loss_sum, ntok, aux = one_micro(params, batch)
+            grads, loss_sum, ntok, aux, stats = one_micro(params, batch)
 
         grads = norm_replicated_over_fsdp(grads)
         if setup.rtob:
@@ -681,7 +694,7 @@ def make_step(setup: TrainSetup, accum: int = 1, xent_chunk: int = 1024):
 
         new_params, new_opt, gnorm = update_fn(params, grads,
                                                state["opt"], lr)
-        metrics = train_metrics(setup, loss_sum, ntok, gnorm, aux)
+        metrics = train_metrics(setup, loss_sum, ntok, gnorm, aux, stats)
         new_state = {"step": state["step"] + 1, "params": new_params,
                      "opt": new_opt, "agg": new_agg}
         return new_state, metrics
@@ -692,9 +705,7 @@ def make_step(setup: TrainSetup, accum: int = 1, xent_chunk: int = 1024):
         bspecs = batch_spec_fn(batch_example)
         f = shard_map(step_fn, setup.mesh,
                       in_specs=(setup.state_specs, bspecs, P()),
-                      out_specs=(setup.state_specs,
-                                 {"loss": P(), "tokens": P(),
-                                  "grad_norm": P(), "moe_aux": P()}))
+                      out_specs=(setup.state_specs, P()))
         return jax.jit(f, donate_argnums=(0,))
 
     return jitted

@@ -106,12 +106,13 @@ def test_qsgd_quantize_matches_ref(n, levels):
 BF16_TOL = 2 * 2.0 ** -7
 
 
-def _attn_inputs(h, kvh, s=256, hd=64, b=1):
+def _attn_inputs(h, kvh, s=256, hd=64, b=1, dv=None):
+    dv = hd if dv is None else dv
     kq, kk, kv, kd = jax.random.split(jax.random.key(h * 10 + kvh), 4)
     q = jax.random.normal(kq, (b, s, h, hd), jnp.bfloat16)
     k = jax.random.normal(kk, (b, s, kvh, hd), jnp.bfloat16)
-    v = jax.random.normal(kv, (b, s, kvh, hd), jnp.bfloat16)
-    do = jax.random.normal(kd, (b, s, h, hd), jnp.bfloat16)
+    v = jax.random.normal(kv, (b, s, kvh, dv), jnp.bfloat16)
+    do = jax.random.normal(kd, (b, s, h, dv), jnp.bfloat16)
     return q, k, v, do
 
 
@@ -149,6 +150,45 @@ def test_flash_attention_matches_jnp(h, kvh, causal, blocks):
     _close(out, want)
     for got_g, want_g in zip(vjp(do), want_vjp(do)):
         _close(got_g, want_g)
+
+
+@pytest.mark.parametrize("hd,dv,causal", [
+    (192, 128, True), (192, 128, False), (128, 64, True)],
+    ids=["mla-causal", "mla-full", "v-half-causal"])
+def test_flash_attention_smaller_v_head_matches_jnp(hd, dv, causal):
+    """A v head smaller than the q/k head (latent attention: 192 and
+    128): the kernel's output and q/k/v gradients against the jnp loop,
+    and the dispatch takes the kernel."""
+    from repro.kernels import flash_attention as fa
+    from repro.kernels import ops
+    from repro.models.attention import chunked_attention
+    q, k, v, do = _attn_inputs(4, 4, hd=hd, dv=dv)
+    assert ops.flash_attention_fits(q.shape, k.shape, v.shape,
+                                    force="pallas")
+    hm = (0, 2, 1, 3)
+
+    def kernel(q, k, v):
+        return fa.flash_attention(
+            q.transpose(hm), k.transpose(hm), v.transpose(hm),
+            causal=causal, blocks=(128, 128, 128, 128),
+            interpret=True).transpose(hm)
+
+    def loop(q, k, v):
+        return chunked_attention(q, k, v, causal=causal, force="ref")
+
+    out, vjp = jax.vjp(kernel, q, k, v)
+    want, want_vjp = jax.vjp(loop, q, k, v)
+    assert out.shape == want.shape == do.shape
+    _close(out, want)
+    for got_g, want_g in zip(vjp(do), want_vjp(do)):
+        _close(got_g, want_g)
+
+
+def test_flash_attention_refuses_a_larger_v_head():
+    from repro.kernels import flash_attention as fa
+    assert fa.fits(256, 256, 128, 128) and fa.fits(256, 256, 192, 128)
+    assert not fa.fits(256, 256, 128, 192)
+    assert not fa.fits(256, 256, 192, 96)
 
 
 def test_flash_attention_dispatch_takes_kernel():
@@ -195,3 +235,56 @@ def test_flash_attention_dispatch_falls_back(case):
     want = chunked_attention(q, k, v, force="ref", **kw)
     np.testing.assert_array_equal(np.asarray(out, np.float32),
                                   np.asarray(want, np.float32))
+
+
+# ------------------------------------------------------- grouped matmul
+#: rows per tile and the tiles each group holds: groups with no rows, and
+#: every row on one group
+GMM_LAYOUTS = {"mixed": [2, 0, 3, 1], "one-group": [0, 0, 5, 0],
+               "empty-first": [0, 1, 1, 2]}
+TM = 8
+
+
+def _gmm_loop(x, w, tiles):
+    """Each group's rows times its matrix, one group at a time; rows past
+    the groups read zero."""
+    out, r = jnp.zeros((x.shape[0], w.shape[2]), jnp.float32), 0
+    for g, n in enumerate(tiles):
+        n *= TM
+        out = out.at[r:r + n].set(
+            jnp.dot(x[r:r + n], w[g], precision="highest"))
+        r += n
+    return out
+
+
+@pytest.mark.parametrize("force", ["pallas", "ref"])
+@pytest.mark.parametrize("layout", sorted(GMM_LAYOUTS))
+def test_grouped_matmul_matches_a_loop(layout, force):
+    """The grouped matmul (the Pallas kernel in interpret mode, or the
+    ragged_dot fallback) and its gradients against a loop over the
+    groups, on the rows the groups hold."""
+    from repro.kernels import ops
+    tiles = GMM_LAYOUTS[layout]
+    rows = (sum(tiles) + 2) * TM               # two tiles hold no rows
+    valid = sum(tiles) * TM
+    kx, kw, kd = jax.random.split(jax.random.key(len(layout)), 3)
+    x = jax.random.normal(kx, (rows, 128), jnp.float32)
+    w = jax.random.normal(kw, (len(tiles), 128, 256), jnp.float32)
+    dy = jax.random.normal(kd, (rows, 256), jnp.float32)
+    gt = jnp.asarray(tiles, jnp.int32)
+
+    def kernel(x, w):
+        return ops.grouped_matmul(x, w, gt, tm=TM, force=force)[:valid]
+
+    with jax.default_matmul_precision("highest"):
+        out, vjp = jax.vjp(kernel, x, w)
+        want, want_vjp = jax.vjp(
+            lambda x, w: _gmm_loop(x, w, tiles)[:valid], x, w)
+        (dx, dw), (dx_w, dw_w) = vjp(dy[:valid]), want_vjp(dy[:valid])
+    tol = dict(rtol=1e-5, atol=1e-3)
+    np.testing.assert_allclose(out, want, **tol)
+    np.testing.assert_allclose(dx[:valid], dx_w[:valid], **tol)
+    np.testing.assert_allclose(dw, dw_w, **tol)
+    for g, n in enumerate(tiles):
+        if n == 0:
+            assert not np.any(np.asarray(dw[g])), g

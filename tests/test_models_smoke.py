@@ -55,7 +55,7 @@ def test_smoke_train_step(arch):
     _, train, _, _ = _batches(cfg, B, S, jax.random.key(1))
 
     def loss_fn(p):
-        loss, ntok, aux = m.loss(p, train, ctx)
+        loss, ntok, aux, _ = m.loss(p, train, ctx)
         return loss / jnp.maximum(ntok, 1)
 
     loss, grads = jax.value_and_grad(loss_fn)(params)
@@ -137,6 +137,7 @@ def test_full_param_counts_match_public_sizes():
         "xlstm-350m": (0.3e9, 0.45e9),
         "qwen2-vl-7b": (6.5e9, 8.5e9),
         "seamless-m4t-medium": (0.55e9, 1.2e9),
+        "moonlight-16b-a3b": (15.5e9, 16.5e9),
     }
     for name, (lo, hi) in expect.items():
         n = registry.param_count(base.get(name))
@@ -146,6 +147,8 @@ def test_full_param_counts_match_public_sizes():
     assert 2.0e9 <= a <= 3.5e9, a
     a = registry.param_count(base.get("arctic-480b"), active_only=True)
     assert 12e9 <= a <= 25e9, a
+    a = registry.param_count(base.get("moonlight-16b-a3b"), active_only=True)
+    assert 2.5e9 <= a <= 3.5e9, a
 
 
 def test_ssd_and_mlstm_match_reference():
@@ -173,3 +176,31 @@ def test_ssd_and_mlstm_match_reference():
     yc, cc = mlstm_chunked(q, kk, v, ig, fg, chunk=8)
     np.testing.assert_allclose(yr, yc, rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(cr[0], cc[0], rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("fault", ["none", "truncated_tiles", "lost_row"])
+def test_dropfree_counts_uncomputed_slots(fault, monkeypatch):
+    """The drop-free layer's ``dropped_slots`` reads 0 on a sound dispatch
+    and moves when the grouped matmul is given one tile too few, or when a
+    slot's row in the buffer loses its token."""
+    from repro.models import moe
+    cfg = base.reduced(base.get("moonlight-16b-a3b"))
+    ctx = ShardCtx(compute_dtype=jnp.float32)
+    params, _ = moe.dropfree_init(jax.random.key(0), cfg, ctx)
+    x = jax.random.normal(jax.random.key(1), (2, 16, cfg.d_model))
+    plan = moe.dispatch_plan
+
+    def planted(idx, mc, tm):
+        slot_row, row_tok, row_slot, tiles = plan(idx, mc, tm)
+        if fault == "truncated_tiles":
+            tiles = tiles.at[jnp.argmax(tiles)].add(-1)
+        elif fault == "lost_row":       # row 0 always holds a slot
+            row_tok = row_tok.at[0].set(idx.shape[0])
+        return slot_row, row_tok, row_slot, tiles
+    monkeypatch.setattr(moe, "dispatch_plan", planted)
+    _, stats = moe.dropfree_apply(params, x, ctx, cfg, tm=8)
+    assert int(stats["held_slots"]) == 2 * 16 * cfg.moe.top_k
+    if fault == "none":
+        assert int(stats["dropped_slots"]) == 0
+    else:
+        assert int(stats["dropped_slots"]) > 0

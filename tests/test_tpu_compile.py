@@ -99,3 +99,57 @@ def test_flash_attention_compiles_for_v5e(cell, one_chip):
     # forward, its recompute, dK/dV, dQ
     assert len(calls) == 4, calls
     assert all(re.fullmatch(r"%attention\.\d+", c) for c in calls), calls
+
+
+def test_mla_flash_attention_compiles_for_v5e(one_chip):
+    """Latent attention as the Moonlight cell runs it (2 x 8192 tokens,
+    16 heads, q/k 192 and v 128) under remat: four Mosaic calls named
+    ``attention``."""
+    import re
+
+    from repro.kernels import flash_attention as fa
+    qk = jax.ShapeDtypeStruct((2, 16, 8192, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, 16, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def grads(q, k, v):
+        def loss(q, k, v):
+            o = jax.checkpoint(lambda *a: fa.flash_attention(
+                *a, causal=True))(q, k, v)
+            return jnp.sum(o.astype(jnp.float32) ** 2)
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(grads).lower(qk, qk, v).compile().as_text()
+    calls = [ln.split(" = ", 1)[0].strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " = " in ln]
+    assert len(calls) == 4, calls
+    assert all(re.fullmatch(r"%attention\.\d+", c) for c in calls), calls
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)],
+                         ids=["gate-up", "down"])
+def test_grouped_matmul_compiles_for_v5e(k, n, one_chip):
+    """The drop-free layer's grouped matmul at Moonlight's widths (8 held
+    experts, the worst-case row buffer of 2 x 8192 tokens at top-6),
+    forward and both backward products: three Mosaic calls named
+    ``moe_gmm``."""
+    import re
+
+    from repro.kernels import gmm
+    tm, g = 256, 8
+    rows = -(-(16384 * 6 + g * (tm - 1)) // tm) * tm
+    x = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((g, k, n), jnp.bfloat16, sharding=one_chip)
+    tiles = jax.ShapeDtypeStruct((g,), jnp.int32, sharding=one_chip)
+
+    def fwd_bwd(x, w, tiles):
+        y, vjp = jax.vjp(lambda x, w: gmm.grouped_matmul(x, w, tiles, tm),
+                         x, w)
+        return y, vjp(y)
+
+    text = jax.jit(fwd_bwd).lower(x, w, tiles).compile().as_text()
+    calls = [ln.split(" = ", 1)[0].strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " = " in ln]
+    assert len(calls) == 3, calls
+    assert all(re.fullmatch(r"%moe_gmm(\.\d+)?", c) for c in calls), calls
